@@ -85,9 +85,10 @@ def compiled_overhead():
     from repro.amr.compiled import CompiledAMRConfig, make_uniform_step
     from repro.amr.wave import WaveProblem
 
-    from repro.distributed.compat import make_mesh
+    from jax.sharding import AxisType
 
-    mesh = make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     prob = WaveProblem(rmax=20.0, amplitude=0.005)
     cfg = CompiledAMRConfig(grain=64, slots=32, n_steps=8)
     step, mk, init, to_g, shd, info = make_uniform_step(

@@ -1512,6 +1512,8 @@ if __name__ == "__main__":
                          "it, so runs are reproducible across "
                          "machines")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run(out_path=args.out, smoke=args.smoke, kv_shards=args.kv_shards,
         tiering=args.tiering, host_pages=args.host_pages,
         prefix_heavy=args.prefix_heavy, seed=args.seed,

@@ -146,9 +146,8 @@ def make_uniform_step(prob: WaveProblem, cfg: CompiledAMRConfig,
                 cfg.use_pallas)
         return u[None]                        # (1, S, 3, g)
 
-    from repro.distributed.compat import shard_map
-    inner = shard_map(local_step, mesh=mesh, in_specs=(spec,),
-                      out_specs=spec, check=False)
+    inner = jax.shard_map(local_step, mesh=mesh, in_specs=(spec,),
+                          out_specs=spec, check_vma=False)
 
     def step_fn(pool: jnp.ndarray) -> jnp.ndarray:
         def body(p_, _):

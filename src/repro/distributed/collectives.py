@@ -116,10 +116,9 @@ def make_hierarchical_grad_reducer(mesh: Mesh):
             flat = jnp.pad(flat, (0, pad))
             out = hierarchical_psum(flat, "pod", "data")
             return out[:g.size].reshape(g.shape)
-        from repro.distributed.compat import shard_map
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda t: jax.tree.map(one, t), mesh=mesh,
-            in_specs=P(), out_specs=P(), check=False)
+            in_specs=P(), out_specs=P(), check_vma=False)
         return fn(grads)
 
     return reduce_tree

@@ -25,7 +25,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.models.config import ArchConfig, ShapeConfig
@@ -237,12 +237,11 @@ def kv_pool_mesh(n_shards: int, kv_axis: str = "kv"):
     bit-identical in results and lets the same engine config run in
     unit tests and on real meshes.
     """
-    import jax
     nd = jax.device_count()
     if n_shards <= 1 or nd < n_shards or nd % n_shards:
         return None
-    from repro.distributed.compat import make_mesh
-    return make_mesh((nd // n_shards, n_shards), ("data", kv_axis))
+    return jax.make_mesh((nd // n_shards, n_shards), ("data", kv_axis),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def constrain(x, mesh: Mesh, *spec):

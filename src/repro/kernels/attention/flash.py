@@ -21,11 +21,14 @@ S_eff accounting.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 NEG_INF = -1.0e30
 
@@ -89,7 +92,7 @@ def flash_attention_bhsd(q: jnp.ndarray, k: jnp.ndarray,
                          v: jnp.ndarray, *, causal: bool = True,
                          window: int = 0, q_offset: int = 0,
                          n_rep: int = 1, bq: int = 128, bk: int = 128,
-                         interpret: bool = True) -> jnp.ndarray:
+                         interpret: Optional[bool] = None) -> jnp.ndarray:
     """q: (BH, Sq, D); k/v: (BKV, Sk, D) with BH = BKV * n_rep."""
     bh, sq, d = q.shape
     sk = k.shape[1]
@@ -116,5 +119,5 @@ def flash_attention_bhsd(q: jnp.ndarray, k: jnp.ndarray,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)

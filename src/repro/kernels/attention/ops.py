@@ -2,7 +2,6 @@
 
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -11,13 +10,6 @@ import jax.numpy as jnp
 from repro.kernels.attention.flash import flash_attention_bhsd
 from repro.kernels.attention.paged import (paged_attention_bhd,
                                            paged_prefill_attention_btd)
-
-
-def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
 
 
 @partial(jax.jit,
@@ -40,7 +32,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     vf = v.transpose(0, 2, 1, 3).reshape(b * kvh, v.shape[1], d)
     out = flash_attention_bhsd(
         qf, kf, vf, causal=causal, window=window, q_offset=q_offset,
-        n_rep=n_rep, bq=bq, bk=bk, interpret=_interpret_default())
+        n_rep=n_rep, bq=bq, bk=bk)
     return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
 
 
@@ -57,7 +49,7 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     kernels.attention.ref.paged_attention_ref."""
     out = paged_attention_bhd(
         q[:, 0], k_pages, v_pages, block_tables, positions,
-        window=window, interpret=_interpret_default())
+        window=window)
     return out[:, None]
 
 
@@ -74,5 +66,4 @@ def paged_prefill_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     each chunk's first query.  Same contract as
     kernels.attention.ref.paged_prefill_attention_ref."""
     return paged_prefill_attention_btd(
-        q, k_pages, v_pages, block_tables, start, window=window,
-        interpret=_interpret_default())
+        q, k_pages, v_pages, block_tables, start, window=window)

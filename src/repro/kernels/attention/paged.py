@@ -15,45 +15,99 @@ runs, so the k/v index maps dereference it to pick the physical page
 row each grid step DMAs into VMEM — the AGAS lookup compiled into an
 index map, with no gather materialized in HBM.
 
-Tiling: grid = (B, H, nP) with the page axis LAST (sequential);
+Tiling: grid = (B, nP) with the page axis LAST (sequential);
 online-softmax statistics (m, l) and the output accumulator persist in
-VMEM scratch across the nP steps of one (B, H) tile and are flushed on
-the final step (same scheme as flash.py).
+VMEM scratch across the nP steps of one sequence and are flushed on the
+final step (same scheme as flash.py).  Every block spans whole trailing
+dimensions, which is what the TPU lowering requires of a block whose
+trailing dims are not (8, 128)-aligned:
 
-  q tile  : (1, T, 1, D) VMEM       k/v tile: (1, ps, 1, D) VMEM
-  scratch : acc (T, D) f32, m (T, 1) f32, l (T, 1) f32
-  (decode is the T == 1 special case with its own entry point)
+  k/v tile: (1, ps, KV, D) — one page, ALL kv heads, DMAed once
+  decode  : q/out (1, H, D); scratch acc (H, D), m/l (H, 1) f32
+  prefill : q/out (1, T, H, D); scratch acc (H, T, D), m/l (H, T, 1)
 
-GQA is handled in the k/v index maps (head h reads kv head
-h // n_rep); pages entirely outside the slot's valid range — beyond
-its per-slot position counter (or the chunk's last query) or behind
-its sliding window — are skipped via @pl.when, so compute scales with
-the tokens actually resident, not with the table width.
+GQA is handled inside the body: kv head g serves query heads
+g*n_rep .. (g+1)*n_rep - 1, so each page is read once per sequence no
+matter how many query heads share it (decode groups those n_rep heads
+into one (n_rep, D) x (D, ps) matmul).  Pages entirely outside the
+slot's valid range — beyond its per-slot position counter (or the
+chunk's last query) or behind its sliding window — are skipped via
+@pl.when, so compute scales with the tokens actually resident, not
+with the table width.
+
+`interpret=None` runs the compiled kernel on a TPU and the Pallas
+interpreter everywhere else; the platform alone decides.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1.0e30
 
 
-def _kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-            acc_ref, m_ref, l_ref, *, ps, n_pages, window, scale,
-            sharded=False):
+def _kv_spec(k_pages: jnp.ndarray):
+    """BlockSpec of one page (all kv heads) picked through the block
+    table: (N, ps, KV, D) flat pools, or (S, R, ps, KV, D) sharded
+    pools whose rows encode ``locality * R + slot`` — the index map
+    performs the AGAS (locality, slot) decode."""
+    ps, kvh, d = k_pages.shape[-3:]
+    if k_pages.ndim == 5:
+        rps = k_pages.shape[1]             # rows per shard
+
+        def kv_map(bi, pi, bt, _):
+            row = bt[bi, pi]
+            return (row // rps, row % rps, 0, 0, 0)
+        return pl.BlockSpec((1, 1, ps, kvh, d), kv_map)
+    return pl.BlockSpec((1, ps, kvh, d),
+                        lambda bi, pi, bt, _: (bt[bi, pi], 0, 0, 0))
+
+
+def _page(ref, g: int, sharded: bool) -> jnp.ndarray:
+    """kv head g of the page in VMEM: (ps, D)."""
+    return ref[0, 0, :, g] if sharded else ref[0, :, g]
+
+
+def _init_stats(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _online_softmax(q, k, v, mask, scale, acc_ref, m_ref, l_ref, at):
+    """One page's contribution to the running softmax of the query
+    rows `q` (n, D) against k/v (ps, D); `at` indexes their scratch."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale   # (n, ps)
+    s = jnp.where(mask, s, NEG_INF)
+    m_prev = m_ref[at]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    pr = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[at] = l_ref[at] * corr + jnp.sum(pr, axis=-1, keepdims=True)
+    acc_ref[at] = acc_ref[at] * corr + jax.lax.dot(
+        pr.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    m_ref[at] = m_new
+
+
+def _decode_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
+                   acc_ref, m_ref, l_ref, *, ps, kvh, n_rep, n_pages,
+                   window, scale, sharded):
     b = pl.program_id(0)
-    p = pl.program_id(2)
+    p = pl.program_id(1)
 
     @pl.when(p == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _init_stats(acc_ref, m_ref, l_ref)
 
     pos = pos_ref[b]
     base = p * ps
@@ -63,29 +117,15 @@ def _kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _body():
-        q = q_ref[0]                       # (1, D)
-        # sharded pools DMA a (1, 1, ps, 1, D) block (locality axis
-        # resolved by the index map); flat pools a (1, ps, 1, D) one
-        k = k_ref[0, 0, :, 0] if sharded else k_ref[0, :, 0]   # (ps, D)
-        v = v_ref[0, 0, :, 0] if sharded else v_ref[0, :, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (1, ps)
-        j = base + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+        j = base + jax.lax.broadcasted_iota(jnp.int32, (n_rep, ps), 1)
         mask = j <= pos
         if window > 0:
             mask &= pos - j < window
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        pr = jnp.exp(s - m_new)
-        pr = jnp.where(mask, pr, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(pr, axis=-1,
-                                                 keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(
-            pr.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        for g in range(kvh):
+            rows = slice(g * n_rep, (g + 1) * n_rep)
+            _online_softmax(q_ref[0, rows], _page(k_ref, g, sharded),
+                            _page(v_ref, g, sharded), mask, scale,
+                            acc_ref, m_ref, l_ref, rows)
 
     @pl.when(p == n_pages - 1)
     def _flush():
@@ -98,71 +138,50 @@ def paged_attention_bhd(q: jnp.ndarray, k_pages: jnp.ndarray,
                         block_tables: jnp.ndarray,
                         positions: jnp.ndarray, *,
                         window: int = 0,
-                        interpret: bool = True) -> jnp.ndarray:
+                        interpret: Optional[bool] = None) -> jnp.ndarray:
     """q: (B, H, D); k/v_pages: (N, ps, KV, D) — or (S, R, ps, KV, D)
     for a locality-sharded pool, where block-table rows encode
     ``locality * R + slot`` and the index map performs the AGAS
     (locality, slot) decode; block_tables: (B, P) int32 physical rows;
     positions: (B,) int32 per-slot clocks.  Returns (B, H, D)."""
     b, h, d = q.shape
-    sharded = k_pages.ndim == 5
     ps, kvh = k_pages.shape[-3], k_pages.shape[-2]
-    n_rep = h // kvh
     n_tables = block_tables.shape[1]
     kern = functools.partial(
-        _kernel, ps=ps, n_pages=n_tables, window=window,
-        scale=d ** -0.5, sharded=sharded)
-
-    # index maps see the scalar-prefetch refs appended to grid indices
-    if sharded:
-        rps = k_pages.shape[1]             # rows per shard
-
-        def kv_map(bi, hi, pi, bt, pos):
-            row = bt[bi, pi]
-            return (row // rps, row % rps, 0, hi // n_rep, 0)
-        kv_spec = pl.BlockSpec((1, 1, ps, 1, d), kv_map)
-    else:
-        def kv_map(bi, hi, pi, bt, pos):
-            return (bt[bi, pi], 0, hi // n_rep, 0)
-        kv_spec = pl.BlockSpec((1, ps, 1, d), kv_map)
-
+        _decode_kernel, ps=ps, kvh=kvh, n_rep=h // kvh,
+        n_pages=n_tables, window=window, scale=d ** -0.5,
+        sharded=k_pages.ndim == 5)
+    kv_spec = _kv_spec(k_pages)
+    q_spec = pl.BlockSpec((1, h, d), lambda bi, pi, bt, _: (bi, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h, n_tables),
-        in_specs=[
-            pl.BlockSpec((1, 1, d), lambda bi, hi, pi, bt, pos:
-                         (bi, hi, 0)),
-            kv_spec,
-            kv_spec,
-        ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda bi, hi, pi, bt, pos:
-                               (bi, hi, 0)),
+        grid=(b, n_tables),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((h, d), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(block_tables.astype(jnp.int32), positions.astype(jnp.int32),
       q, k_pages, v_pages)
 
 
 def _prefill_kernel(bt_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
-                    acc_ref, m_ref, l_ref, *, t, ps, n_pages, window,
-                    scale, sharded=False):
+                    acc_ref, m_ref, l_ref, *, t, ps, h, n_rep, n_pages,
+                    window, scale, sharded):
     b = pl.program_id(0)
-    p = pl.program_id(2)
+    p = pl.program_id(1)
 
     @pl.when(p == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _init_stats(acc_ref, m_ref, l_ref)
 
     start = start_ref[b]
     base = p * ps
@@ -175,33 +194,22 @@ def _prefill_kernel(bt_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _body():
-        q = q_ref[0, :, 0]                 # (T, D)
-        k = k_ref[0, 0, :, 0] if sharded else k_ref[0, :, 0]   # (ps, D)
-        v = v_ref[0, 0, :, 0] if sharded else v_ref[0, :, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (T, ps)
         j = base + jax.lax.broadcasted_iota(jnp.int32, (t, ps), 1)
         qpos = start + jax.lax.broadcasted_iota(jnp.int32, (t, ps), 0)
         mask = j <= qpos                   # causal across + within chunk
         if window > 0:
             mask &= qpos - j < window
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        pr = jnp.exp(s - m_new)
-        pr = jnp.where(mask, pr, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(pr, axis=-1,
-                                                 keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(
-            pr.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        for hi in range(h):
+            g = hi // n_rep
+            _online_softmax(q_ref[0, :, hi], _page(k_ref, g, sharded),
+                            _page(v_ref, g, sharded), mask, scale,
+                            acc_ref, m_ref, l_ref, hi)
 
     @pl.when(p == n_pages - 1)
     def _flush():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        for hi in range(h):
+            l = jnp.maximum(l_ref[hi], 1e-30)
+            o_ref[0, :, hi] = (acc_ref[hi] / l).astype(o_ref.dtype)
 
 
 def paged_prefill_attention_btd(q: jnp.ndarray, k_pages: jnp.ndarray,
@@ -209,7 +217,8 @@ def paged_prefill_attention_btd(q: jnp.ndarray, k_pages: jnp.ndarray,
                                 block_tables: jnp.ndarray,
                                 start: jnp.ndarray, *,
                                 window: int = 0,
-                                interpret: bool = True) -> jnp.ndarray:
+                                interpret: Optional[bool] = None
+                                ) -> jnp.ndarray:
     """Chunked-prefill attention over block tables.
 
     q: (B, T, H, D) chunk queries; k/v_pages: (N, ps, KV, D) — or
@@ -222,48 +231,30 @@ def paged_prefill_attention_btd(q: jnp.ndarray, k_pages: jnp.ndarray,
     the sliding window when set).  Returns (B, T, H, D).
     """
     b, t, h, d = q.shape
-    sharded = k_pages.ndim == 5
     ps, kvh = k_pages.shape[-3], k_pages.shape[-2]
-    n_rep = h // kvh
     n_tables = block_tables.shape[1]
     kern = functools.partial(
-        _prefill_kernel, t=t, ps=ps, n_pages=n_tables, window=window,
-        scale=d ** -0.5, sharded=sharded)
-
-    if sharded:
-        rps = k_pages.shape[1]             # rows per shard
-
-        def kv_map(bi, hi, pi, bt, st):
-            row = bt[bi, pi]
-            return (row // rps, row % rps, 0, hi // n_rep, 0)
-        kv_spec = pl.BlockSpec((1, 1, ps, 1, d), kv_map)
-    else:
-        def kv_map(bi, hi, pi, bt, st):
-            return (bt[bi, pi], 0, hi // n_rep, 0)
-        kv_spec = pl.BlockSpec((1, ps, 1, d), kv_map)
-
-    def q_map(bi, hi, pi, bt, st):
-        return (bi, 0, hi, 0)
-
+        _prefill_kernel, t=t, ps=ps, h=h, n_rep=h // kvh,
+        n_pages=n_tables, window=window, scale=d ** -0.5,
+        sharded=k_pages.ndim == 5)
+    kv_spec = _kv_spec(k_pages)
+    q_spec = pl.BlockSpec((1, t, h, d),
+                          lambda bi, pi, bt, _: (bi, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h, n_tables),
-        in_specs=[
-            pl.BlockSpec((1, t, 1, d), q_map),
-            kv_spec,
-            kv_spec,
-        ],
-        out_specs=pl.BlockSpec((1, t, 1, d), q_map),
+        grid=(b, n_tables),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((t, d), jnp.float32),
-            pltpu.VMEM((t, 1), jnp.float32),
-            pltpu.VMEM((t, 1), jnp.float32),
+            pltpu.VMEM((h, t, d), jnp.float32),
+            pltpu.VMEM((h, t, 1), jnp.float32),
+            pltpu.VMEM((h, t, 1), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, t, h, d), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(block_tables.astype(jnp.int32), start.astype(jnp.int32),
       q, k_pages, v_pages)

@@ -2,7 +2,6 @@
 
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -11,16 +10,8 @@ import jax.numpy as jnp
 from repro.kernels.scan.selective_scan import selective_scan
 
 
-def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
-
-
 @partial(jax.jit, static_argnames=("chunk", "d_block"))
 def selective_scan_op(da: jnp.ndarray, dbx: jnp.ndarray,
                       c: jnp.ndarray, *, chunk: int = 128,
                       d_block: int = 256) -> jnp.ndarray:
-    return selective_scan(da, dbx, c, chunk=chunk, d_block=d_block,
-                          interpret=_interpret_default())
+    return selective_scan(da, dbx, c, chunk=chunk, d_block=d_block)

@@ -21,11 +21,14 @@ state dim (16); time steps inside a chunk run in a fori_loop over VMEM.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 
 def _kernel(da_ref, dbx_ref, c_ref, y_ref, h_ref, *, chunk):
@@ -50,7 +53,7 @@ def _kernel(da_ref, dbx_ref, c_ref, y_ref, h_ref, *, chunk):
 
 def selective_scan(da: jnp.ndarray, dbx: jnp.ndarray, c: jnp.ndarray,
                    *, chunk: int = 128, d_block: int = 256,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret: Optional[bool] = None) -> jnp.ndarray:
     """da/dbx: (B, S, D, N) f32; c: (B, S, N) f32 -> y (B, S, D) f32."""
     b, s, d, n = da.shape
     chunk = min(chunk, s)
@@ -74,5 +77,5 @@ def selective_scan(da: jnp.ndarray, dbx: jnp.ndarray, c: jnp.ndarray,
                                lambda bi, di, ci: (bi, ci, di)),
         out_shape=jax.ShapeDtypeStruct((b, s, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((d_block, n), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(da, dbx, c)

@@ -3,26 +3,18 @@
 `stencil_rk3_step` is what amr/compiled.py calls when
 CompiledAMRConfig.use_pallas is set: it adapts the pool layout
 (slots, 3, g+2H) + broadcast masks to the kernel's (nb, ...) layout.
-On CPU the kernel runs in interpret mode (env REPRO_PALLAS_INTERPRET
-defaults to 1 there); on TPU set it to 0 for the compiled kernel.
+The kernel compiles on a TPU and runs in the Pallas interpreter
+everywhere else (repro.kernels.interpret_mode).
 """
 
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.stencil.stencil import stencil_rk3
-
-
-def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
 
 
 @partial(jax.jit, static_argnames=("dr", "dt", "p"))
@@ -34,5 +26,4 @@ def stencil_rk3_step(pool_ext: jnp.ndarray, r_ext: jnp.ndarray,
     flags = jnp.stack(
         [left_phys.reshape(nb).astype(jnp.int32),
          right_phys.reshape(nb).astype(jnp.int32)], axis=-1)
-    return stencil_rk3(pool_ext, r_ext, flags, dr=dr, dt=dt, p=p,
-                       interpret=_interpret_default())
+    return stencil_rk3(pool_ext, r_ext, flags, dr=dr, dt=dt, p=p)

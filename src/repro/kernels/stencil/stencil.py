@@ -21,10 +21,13 @@ is HBM avoidance, not MXU utilization.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_mode
 
 H = 3  # halo width (1 stencil radius x 3 RK stages)
 
@@ -82,7 +85,7 @@ def _kernel(u_ref, r_ref, flags_ref, o_ref, *, dr, dt, p):
 
 def stencil_rk3(u_ext: jnp.ndarray, r_ext: jnp.ndarray,
                 flags: jnp.ndarray, *, dr: float, dt: float, p: int,
-                interpret: bool = True) -> jnp.ndarray:
+                interpret: Optional[bool] = None) -> jnp.ndarray:
     """u_ext: (nb, 3, g+2H); r_ext: (nb, g+2H); flags: (nb, 2) int32.
 
     Returns (nb, 3, g).
@@ -101,5 +104,5 @@ def stencil_rk3(u_ext: jnp.ndarray, r_ext: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((1, 3, g), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, 3, g), u_ext.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(u_ext, r_ext, flags)

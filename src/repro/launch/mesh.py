@@ -10,21 +10,22 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-
-from repro.distributed.compat import make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 (512 chips, 2 pods)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1):
     """Whatever devices exist locally, as (data, model)."""
     n = len(jax.devices())
-    return make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 # TPU v5e constants for the roofline analysis (task statement).

@@ -1,7 +1,10 @@
-"""Serving driver: reduced-config batched decode demo.
+"""Serving driver: batched decode demo, reduced config by default.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch yi-6b --requests 8
+  PYTHONPATH=src python -m repro.launch.serve --arch yi-6b --published \
+      --pages 1024           # published widths in bf16 (on a TPU; see
+                             # chip_smoke.py for the checked chip run)
   PYTHONPATH=src python -m repro.launch.serve --arch yi-6b \
       --engine paged --pages 24 --page-size 16   # oversubscribed pool
   PYTHONPATH=src python -m repro.launch.serve --arch yi-6b \
@@ -62,6 +65,13 @@ import numpy as np
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
+    ap.add_argument("--published", action="store_true",
+                    help="serve the architecture at its published "
+                         "widths in its own dtype (configs/<arch>.py) "
+                         "instead of the reduced CPU variant")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="depth cut: keep the first N layers of the "
+                         "chosen config (0 = all)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=16)
@@ -165,10 +175,15 @@ def main():
 
     import repro.configs as configs
     from repro.distributed.sharding import kv_pool_mesh
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import transformer as T
     from repro.serving.engine import Request, make_engine
 
-    cfg = configs.get_reduced(args.arch)
+    enable_compile_cache()
+    cfg = configs.get(args.arch) if args.published \
+        else configs.get_reduced(args.arch)
+    if args.layers:
+        cfg = cfg.with_depth(args.layers)
     params = T.init_params(jax.random.PRNGKey(0), cfg)
     failure_plan = None
     if args.chaos_kill_shard >= 0:

@@ -124,6 +124,8 @@ def main():
     args = ap.parse_args()
 
     import repro.configs as configs
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     arch = configs.get_reduced(args.arch) if args.reduced \
         else configs.get(args.arch)
     _, _, losses = train(arch, args.steps, args.batch, args.seq,

@@ -137,6 +137,14 @@ class ArchConfig:
                   self.top_k * 3 * d * ff + d) + d
         return n
 
+    def with_depth(self, n_layers: int) -> "ArchConfig":
+        """The same architecture cut to its first `n_layers` layers —
+        the one cut that keeps every published width."""
+        if not 0 < n_layers <= self.n_layers:
+            raise ValueError(
+                f"{self.name}: depth {n_layers} not in 1..{self.n_layers}")
+        return dataclasses.replace(self, n_layers=n_layers)
+
     def reduced(self) -> "ArchConfig":
         """CPU smoke-test variant: same family/features, tiny sizes."""
         return dataclasses.replace(

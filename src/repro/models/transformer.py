@@ -58,6 +58,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models import attention as att
 from repro.models import moe as moe_mod
@@ -156,9 +157,32 @@ def _cross_layer_init(key, cfg: ArchConfig) -> Params:
     }
 
 
-def _stack_init(key, n: int, fn) -> Params:
-    keys = jax.random.split(key, n)
-    return jax.vmap(fn)(keys)
+@partial(jax.jit, donate_argnums=0)
+def _put_layer(stack: Params, layer: Params, idx) -> Params:
+    return jax.tree.map(lambda s, l: s.at[tuple(idx)].set(l), stack,
+                        layer)
+
+
+def _stack_init(key, dims: Tuple[int, ...], fn) -> Params:
+    """Layer params stacked over `dims` (one axis, or two for grouped
+    stacks).  Each layer is drawn from the key `vmap` over
+    ``random.split(key, dims[0])`` (split again per group) would hand
+    it, with the same eager ops, so the values are bit-identical to
+    that whole-stack draw.  Layers are drawn one at a time into a
+    preallocated stack, so the largest temporary is one layer's
+    float32 draw, never the whole stack's (a 32-layer d_ff stack in
+    float32 alone would not fit a 16 GB chip)."""
+    keys = jax.random.split(key, dims[0])
+    if len(dims) == 2:
+        keys = jax.vmap(lambda k: jax.random.split(k, dims[1]))(keys)
+    stack = None
+    for idx in np.ndindex(*dims):
+        layer = fn(keys[idx])
+        if stack is None:
+            stack = jax.tree.map(
+                lambda a: jnp.zeros(dims + a.shape, a.dtype), layer)
+        stack = _put_layer(stack, layer, jnp.asarray(idx, jnp.int32))
+    return stack
 
 
 def init_params(key, cfg: ArchConfig, tp: int = 1) -> Params:
@@ -174,38 +198,35 @@ def init_params(key, cfg: ArchConfig, tp: int = 1) -> Params:
     fam = cfg.family
     if fam in ("dense", "audio"):
         params["layers"] = _stack_init(
-            ks[2], cfg.n_layers, lambda k: _dense_layer_init(k, cfg))
+            ks[2], (cfg.n_layers,), lambda k: _dense_layer_init(k, cfg))
     elif fam == "moe":
         params["layers"] = _stack_init(
-            ks[2], cfg.n_layers, lambda k: _moe_layer_init(k, cfg, tp))
+            ks[2], (cfg.n_layers,), lambda k: _moe_layer_init(k, cfg, tp))
     elif fam == "ssm":
         params["layers"] = _stack_init(
-            ks[2], cfg.n_layers, lambda k: _ssm_layer_init(k, cfg))
+            ks[2], (cfg.n_layers,), lambda k: _ssm_layer_init(k, cfg))
     elif fam == "hybrid":
         every = cfg.shared_attn_every
         n_groups = cfg.n_layers // every
         tail = cfg.n_layers - n_groups * every
         params["groups"] = _stack_init(
-            ks[2], n_groups,
-            lambda k: _stack_init(k, every,
-                                  lambda k2: _ssm_layer_init(k2, cfg)))
+            ks[2], (n_groups, every), lambda k: _ssm_layer_init(k, cfg))
         params["shared_attn"] = _shared_attn_init(ks[3], cfg)
         params["adapters"] = _stack_init(
-            ks[4], n_groups,
+            ks[4], (n_groups,),
             lambda k: {"w": _init_dense(k, cfg.d_model, cfg.d_model, dt)
                        * 0.1})
         if tail:
             params["tail"] = _stack_init(
-                ks[5], tail, lambda k: _ssm_layer_init(k, cfg))
+                ks[5], (tail,), lambda k: _ssm_layer_init(k, cfg))
     elif fam == "vlm":
         every = cfg.cross_attn_every
         n_groups = cfg.n_layers // every
         params["groups_self"] = _stack_init(
-            ks[2], n_groups,
-            lambda k: _stack_init(k, every - 1,
-                                  lambda k2: _dense_layer_init(k2, cfg)))
+            ks[2], (n_groups, every - 1),
+            lambda k: _dense_layer_init(k, cfg))
         params["groups_cross"] = _stack_init(
-            ks[3], n_groups, lambda k: _cross_layer_init(k, cfg))
+            ks[3], (n_groups,), lambda k: _cross_layer_init(k, cfg))
         params["patch_proj"] = {
             "w": _init_dense(ks[4], _frontend_dim(cfg), cfg.d_model, dt)}
     else:

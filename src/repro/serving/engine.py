@@ -1568,10 +1568,12 @@ class ChunkedPagedServingEngine(PagedServingEngine):
         rows_arr = np.full(self.chunk_size // ps,
                            self.kvc.pool.null_row, np.int32)
         rows_arr[:len(rows)] = rows
+        # a host copy: the table mutates in place while its transfer
+        # may still read it (PagedKVCache.batch_inputs)
+        table = jnp.asarray(self.kvc.tables[slot][None].copy())
         logits, hlast, bh, pages = self._chunk_step(
             self.params, self.kvc.pool.pages,
-            jnp.asarray(toks[None]),
-            jnp.asarray(self.kvc.tables[slot][None]),
+            jnp.asarray(toks[None]), table,
             jnp.asarray([start], jnp.int32),
             jnp.asarray(rows_arr[None]),
             jnp.int32(take - 1))

@@ -68,6 +68,18 @@ from repro.obs.trace import NULL_TRACER
 from repro.serving.radix import RadixPrefixIndex
 
 
+def pages_that_fit(cfg: ArchConfig, page_size: int,
+                   free_bytes: int) -> int:
+    """Pool pages (K and V, every layer) that `free_bytes` of device
+    memory can hold.  A decode or chunk step scans the pool layer by
+    layer and holds its input and its updated copy at once, so each
+    page is counted twice."""
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    page = (2 * cfg.n_layers * page_size * cfg.n_kv_heads * cfg.head_dim
+            * itemsize)
+    return max(int(free_bytes) // (2 * page), 0)
+
+
 class PageExhausted(RuntimeError):
     """No free page in the pool; callers preempt or defer."""
 
@@ -690,7 +702,6 @@ class PagePool:
             return fn
         from jax import lax
         from jax.sharding import PartitionSpec as P
-        from repro.distributed.compat import shard_map
         spec = P(None, self.kv_axis, None, None, None, None)
         axis = self.kv_axis
 
@@ -704,9 +715,9 @@ class PagePool:
                                 cur.at[:, 0, ss[leg, i]].set(got), cur)
             return cur
 
-        fn = jax.jit(shard_map(body, mesh=self.mesh,
-                               in_specs=(spec, P(), P(), P()),
-                               out_specs=spec))
+        fn = jax.jit(jax.shard_map(body, mesh=self.mesh,
+                                   in_specs=(spec, P(), P(), P()),
+                                   out_specs=spec, check_vma=False))
         self._mig_cache[perms] = fn
         return fn
 
@@ -1429,10 +1440,15 @@ class PagedKVCache:
 
     # -- the compiled-step view ---------------------------------------
     def batch_inputs(self) -> Dict[str, Any]:
-        """Fixed-shape arrays for decode_step_paged (one compile)."""
+        """Fixed-shape arrays for decode_step_paged (one compile).
+
+        Built from host copies: a transfer may still read its numpy
+        source after dispatch returns (on the CPU the device array can
+        alias it outright, and `jnp.array` copies on the device, after
+        that read), while the engine advances these tables in place."""
         return {
-            "block_tables": jnp.asarray(self.tables),
-            "positions": jnp.asarray(self.lengths),
-            "write_rows": jnp.asarray(self.write_rows),
-            "write_offs": jnp.asarray(self.write_offs),
+            "block_tables": jnp.asarray(self.tables.copy()),
+            "positions": jnp.asarray(self.lengths.copy()),
+            "write_rows": jnp.asarray(self.write_rows.copy()),
+            "write_offs": jnp.asarray(self.write_offs.copy()),
         }

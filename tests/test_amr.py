@@ -38,7 +38,7 @@ def test_spatial_convergence_second_order():
     for n in (129, 257, 513):
         p = amr.WaveProblem(n_points=n, rmax=16.0, amplitude=0.003,
                             dtype="float64", cfl=0.2)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             u = amr.initial_data(p)
             r = amr.grid(p)
             t_target = 0.5

@@ -26,10 +26,11 @@ def run_sub(code: str, timeout=600) -> str:
 
 def test_compiled_amr_multidevice_matches_reference():
     out = run_sub("""
-        from repro.distributed.compat import make_mesh, shard_map
         import jax, numpy as np
+        from jax.sharding import AxisType
         from repro.amr import wave, compiled as cp
-        mesh = make_mesh((4, 2), ('data', 'model'))
+        mesh = jax.make_mesh((4, 2), ('data', 'model'),
+                             axis_types=(AxisType.Auto,) * 2)
         prob = wave.WaveProblem(rmax=20.0, amplitude=0.005)
         cfg = cp.CompiledAMRConfig(grain=32, slots=4, n_steps=6)
         step, mk, init, to_g, shd, info = cp.make_uniform_step(
@@ -47,15 +48,16 @@ def test_compiled_amr_multidevice_matches_reference():
 
 def test_hierarchical_psum_exact():
     out = run_sub("""
-        from repro.distributed.compat import make_mesh, shard_map
         import jax, numpy as np, jax.numpy as jnp
+        from jax.sharding import AxisType
         from jax.sharding import PartitionSpec as P
         from repro.distributed.collectives import hierarchical_psum
-        mesh = make_mesh((2, 4), ('pod', 'data'))
+        mesh = jax.make_mesh((2, 4), ('pod', 'data'),
+                             axis_types=(AxisType.Auto,) * 2)
         x = jnp.arange(8.0)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda v: hierarchical_psum(v, 'pod', 'data'),
-            mesh=mesh, in_specs=P(), out_specs=P(), check=False)
+            mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)
         got = fn(x)
         np.testing.assert_allclose(np.asarray(got), np.asarray(x) * 8)
         print('HIER_OK')
@@ -65,18 +67,19 @@ def test_hierarchical_psum_exact():
 
 def test_compressed_psum_error_feedback():
     out = run_sub("""
-        from repro.distributed.compat import make_mesh, shard_map
         import jax, numpy as np, jax.numpy as jnp
+        from jax.sharding import AxisType
         from jax.sharding import PartitionSpec as P
         from repro.distributed.collectives import (
             compressed_cross_pod_psum)
-        mesh = make_mesh((8,), ('pod',))
+        mesh = jax.make_mesh((8,), ('pod',),
+                             axis_types=(AxisType.Auto,))
         rng = np.random.default_rng(0)
         g = jnp.asarray(rng.normal(size=(64,)).astype(np.float32))
         def one(x, err):
             return compressed_cross_pod_psum(x, err, 'pod')
-        fn = shard_map(one, mesh=mesh, in_specs=(P(), P()),
-                        out_specs=(P(), P()), check=False)
+        fn = jax.shard_map(one, mesh=mesh, in_specs=(P(), P()),
+                        out_specs=(P(), P()), check_vma=False)
         err = jnp.zeros_like(g)
         # accumulated compressed sums converge to accumulated true sums
         acc_c, acc_t = jnp.zeros_like(g), jnp.zeros_like(g)
@@ -94,15 +97,16 @@ def test_compressed_psum_error_feedback():
 
 def test_sharded_train_step_runs():
     out = run_sub("""
-        from repro.distributed.compat import make_mesh, shard_map
         import jax, numpy as np
+        from jax.sharding import AxisType
         import repro.configs as configs
         from repro.launch import steps as S
         from repro.launch.train import make_state
         from repro.models.config import ShapeConfig
         from repro.optim.adamw import AdamWConfig
         from repro.data.pipeline import DataConfig, SyntheticCorpus
-        mesh = make_mesh((4, 2), ('data', 'model'))
+        mesh = jax.make_mesh((4, 2), ('data', 'model'),
+                             axis_types=(AxisType.Auto,) * 2)
         arch = configs.get_reduced('yi-6b')
         shape = ShapeConfig('t', 64, 8, 'train')
         opt_cfg = AdamWConfig(total_steps=50, warmup_steps=1, lr=5e-3)
@@ -124,12 +128,14 @@ def test_sharded_train_step_runs():
 
 def test_elastic_checkpoint_across_meshes(tmp_path):
     out = run_sub(f"""
-        from repro.distributed.compat import make_mesh, shard_map
         import jax, numpy as np, jax.numpy as jnp
+        from jax.sharding import AxisType
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint.checkpoint import Checkpointer
-        mesh_a = make_mesh((4, 2), ('data', 'model'))
-        mesh_b = make_mesh((2, 4), ('data', 'model'))
+        mesh_a = jax.make_mesh((4, 2), ('data', 'model'),
+                             axis_types=(AxisType.Auto,) * 2)
+        mesh_b = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(AxisType.Auto,) * 2)
         x = jnp.arange(64.0).reshape(8, 8)
         xa = jax.device_put(x, NamedSharding(mesh_a,
                                              P('data', 'model')))
@@ -149,11 +155,12 @@ def test_param_shardings_consistent_on_production_mesh():
     """Rule table produces valid, divisible specs for every arch on a
     small stand-in production mesh."""
     out = run_sub("""
-        from repro.distributed.compat import make_mesh, shard_map
         import jax
+        from jax.sharding import AxisType
         import repro.configs as configs
         from repro.launch import steps as S
-        mesh = make_mesh((2, 4), ('data', 'model'))
+        mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(AxisType.Auto,) * 2)
         for name in configs.ARCHS:
             arch = configs.get_reduced(name)
             pa = S.abstract_params(arch, mesh)   # raises if indivisible

@@ -148,3 +148,53 @@ def test_swa_ring_buffer_decode():
         logits, cache = T.decode_step(params, cache, batch, cfg)
     assert np.all(np.isfinite(np.asarray(logits)))
     assert int(cache["cursor"]) == 40
+
+
+def _vmap_stack(key, n, fn):
+    """The eager whole-stack draw init_params builds one layer at a
+    time: every layer's values must equal what this gives."""
+    return jax.vmap(fn)(jax.random.split(key, n))
+
+
+@pytest.mark.parametrize("name", ["yi-6b", "mixtral-8x7b",
+                                  "falcon-mamba-7b", "zamba2-7b",
+                                  "llama-3.2-vision-90b"])
+def test_layer_stacks_bit_identical_to_vmapped_draw(name):
+    cfg = configs.get_reduced(name)
+    key = jax.random.PRNGKey(7)
+    got = T.init_params(key, cfg)
+    ks = jax.random.split(key, 8)
+    L, fam = cfg.n_layers, cfg.family
+    dense = lambda k: T._dense_layer_init(k, cfg)
+    ssm = lambda k: T._ssm_layer_init(k, cfg)
+    if fam == "dense":
+        want = {"layers": _vmap_stack(ks[2], L, dense)}
+    elif fam == "moe":
+        want = {"layers": _vmap_stack(
+            ks[2], L, lambda k: T._moe_layer_init(k, cfg, 1))}
+    elif fam == "ssm":
+        want = {"layers": _vmap_stack(ks[2], L, ssm)}
+    elif fam == "hybrid":
+        every = cfg.shared_attn_every
+        n = L // every
+        want = {"groups": _vmap_stack(
+                    ks[2], n, lambda k: _vmap_stack(k, every, ssm)),
+                "adapters": _vmap_stack(ks[4], n, lambda k: {
+                    "w": T._init_dense(k, cfg.d_model, cfg.d_model,
+                                       jnp.dtype(cfg.dtype)) * 0.1})}
+    else:
+        every = cfg.cross_attn_every
+        n = L // every
+        want = {"groups_self": _vmap_stack(
+                    ks[2], n, lambda k: _vmap_stack(k, every - 1, dense)),
+                "groups_cross": _vmap_stack(
+                    ks[3], n, lambda k: T._cross_layer_init(k, cfg))}
+    assert set(want) <= set(got)
+    flat_w = jax.tree_util.tree_leaves_with_path(want)
+    flat_g = dict(jax.tree_util.tree_leaves_with_path(
+        {k: got[k] for k in want}))
+    assert len(flat_w) == len(flat_g)
+    for path, w in flat_w:
+        g = flat_g[path]
+        assert g.dtype == w.dtype and g.shape == w.shape, path
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
