@@ -5,6 +5,8 @@ Five layers (ISSUE 6 + ISSUE 9):
 - ``trace``       ring-buffer tracer emitting typed spans/instants with
                   monotonic timestamps and causal ids (request -> slot ->
                   page chain -> parcel); Chrome trace-event JSON export.
+                  Every span, traced or not, is also a profiler
+                  annotation on the device trace's clock.
 - ``metrics``     unified registry of counters / gauges / streaming
                   histograms under a ``subsystem.metric`` namespace.
 - ``attribution`` per-step wall-clock decomposition into kernel compute

@@ -86,16 +86,22 @@ _ROLE_BY_NAME = {
 }
 
 
-def span_role(span):
+def span_role(span, parent_role="other"):
     """Disagg role a span's self time belongs to.
 
     Prefill execution (whole-prompt, chunked, compute-skip resume)
     is prefill-worker work; the decode batch is decode-worker work;
     percolation handoff stage/commit is the copy seam between them.
-    Everything else (admit bookkeeping, page accounting, tier
-    traffic, step glue) is role-neutral runtime -> ``other``.
+    An engine span inside one of those (the decode batch's inputs,
+    dispatch and sampling; a chunk's inputs and first token; a fetch)
+    is its enclosing role's work.  Everything else (admit
+    bookkeeping, page accounting, tier traffic, step glue) is
+    role-neutral runtime -> ``other``.
     """
-    return _ROLE_BY_NAME.get(span.name, "other")
+    role = _ROLE_BY_NAME.get(span.name)
+    if role is not None:
+        return role
+    return parent_role if span.subsystem == "engine" else "other"
 
 
 def span_locality(span):
@@ -110,9 +116,10 @@ def attribute_roles(records, root_subsystem="engine", root_name="step"):
     Same self-time tree walk as ``attribute`` — self times sum to
     step wall by construction — but each span's self time lands in
     (a) the role bucket named by the span (prefill worker vs decode
-    worker vs handoff copy vs role-neutral runtime) and (b) the
-    locality bucket from the span's ``loc`` arg (spans without one
-    aggregate under ``"engine"``).  Under ``--disagg --kv-shards N``
+    worker vs handoff copy vs role-neutral runtime; ``span_role``) and
+    (b) the locality bucket from the span's ``loc`` arg, which engine
+    spans inherit from their parent (spans without one aggregate
+    under ``"engine"``).  Under ``--disagg --kv-shards N``
     this proves *where* overhead lives: which role pays it, and on
     which locality's pool it runs.
     """
@@ -128,18 +135,21 @@ def attribute_roles(records, root_subsystem="engine", root_name="step"):
     wall = 0.0
     for step in steps:
         wall += step.dur
-        stack = [step]
+        stack = [(step, "other", None)]
         while stack:
-            s = stack.pop()
+            s, prole, ploc = stack.pop()
             kids = children.get(s.sid, ())
             self_t = s.dur - sum(k.dur for k in kids)
             if self_t < 0.0:
                 self_t = 0.0
-            roles[span_role(s)] += self_t
-            lkey = span_locality(s)
-            lkey = "engine" if lkey is None else f"loc{lkey}"
+            role = span_role(s, prole)
+            roles[role] += self_t
+            loc = span_locality(s)
+            if loc is None and s.subsystem == "engine":
+                loc = ploc
+            lkey = "engine" if loc is None else f"loc{loc}"
             locs[lkey] = locs.get(lkey, 0.0) + self_t
-            stack.extend(kids)
+            stack.extend((k, role, loc) for k in kids)
     total = sum(roles.values())
     return {
         "steps": len(steps),

@@ -23,9 +23,18 @@ at the time the record was opened.  Records land in a preallocated ring
 (oldest evicted first, ``dropped`` counts evictions) so memory stays
 O(capacity) over arbitrarily long runs.
 
-Disabled tracing is the ``NULL_TRACER`` singleton: every call is a
-constant-time no-op (no clock read, no allocation beyond the call
-itself).  Free-standing subsystems that have no constructor path for a
+Every span, recorded or not, also enters
+``jax.profiler.TraceAnnotation("<subsystem>.<name>")`` for its
+duration, so a running profiler puts it on the device trace's clock:
+the profiler's host events are ``CLOCK_MONOTONIC`` (``perf_counter``)
+less one constant per trace, and ``Tracer`` keeps ``perf_counter``, so
+its records and the annotations differ by that constant alone.  An
+annotation costs about a microsecond with or without a profiler
+running.  Instants stay in the ring buffer only.
+
+Disabled tracing is the ``NULL_TRACER`` singleton: it keeps no records
+and reads no clock of its own; its spans enter only the annotation.
+Free-standing subsystems that have no constructor path for a
 tracer (``core.lco``, ``core.parcels``, ``core.agas``) emit through the
 module-global ``GLOBAL``, rebindable via ``set_global`` — attribute
 lookup at call time, so rebinding takes effect immediately.
@@ -34,6 +43,8 @@ lookup at call time, so rebinding takes effect immediately.
 import json
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Span",
@@ -73,17 +84,19 @@ class Span:
 class _SpanCtx:
     """Context manager opening/closing one span on a live tracer."""
 
-    __slots__ = ("_tr", "_rec")
+    __slots__ = ("_tr", "_rec", "_ann")
 
     def __init__(self, tr, rec):
         self._tr = tr
         self._rec = rec
+        self._ann = TraceAnnotation(f"{rec.subsystem}.{rec.name}")
 
     def __enter__(self):
         tr = self._tr
         rec = self._rec
         stack = tr._stack()
         rec.parent = stack[-1].sid if stack else None
+        self._ann.__enter__()
         rec.t0 = tr.clock()
         stack.append(rec)
         return rec
@@ -92,6 +105,7 @@ class _SpanCtx:
         tr = self._tr
         rec = self._rec
         rec.dur = tr.clock() - rec.t0
+        self._ann.__exit__(exc_type, exc, tb)
         stack = tr._stack()
         if stack and stack[-1] is rec:
             stack.pop()
@@ -109,30 +123,35 @@ class _NullSpan:
 
 
 class _NullCtx:
-    __slots__ = ("_span",)
+    """A span of the disabled tracer: the profiler annotation alone."""
 
-    def __init__(self):
-        self._span = _NullSpan()
+    __slots__ = ("_ann", "_span")
+
+    def __init__(self, label, span):
+        self._ann = TraceAnnotation(label)
+        self._span = span
 
     def __enter__(self):
+        self._ann.__enter__()
         return self._span
 
     def __exit__(self, exc_type, exc, tb):
+        self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
 class NullTracer:
-    """Disabled tracer: every call is a constant-time no-op."""
+    """Disabled tracer: no records; spans enter only their annotation."""
 
     enabled = False
     dropped = 0
     clock = staticmethod(time.perf_counter)
 
     def __init__(self):
-        self._ctx = _NullCtx()
+        self._span = _NullSpan()
 
     def span(self, subsystem, name, kind=None, lane=None, **args):
-        return self._ctx
+        return _NullCtx(f"{subsystem}.{name}", self._span)
 
     def instant(self, subsystem, name, kind=None, lane=None, **args):
         return None

@@ -78,6 +78,33 @@ from repro.serving.workers import (DecodeWorker, HandoffDecodeWorker,
                                    ParcelPrefillWorker, PrefillWorker,
                                    PREFILL_ACTIONS, StepScheduler)
 
+#: The serving step's span tree, by profiler annotation name
+#: (obs/trace.py).  `engine.fetch` nests wherever a blocking
+#: device-to-host read happens, `engine.sample_dispatch` wherever the
+#: sampler dispatches its eager device ops:
+#:
+#:   engine.step
+#:     engine.admit  engine.prefetch
+#:     engine.prefill_chunk            (per chunk)
+#:       engine.chunk_inputs           begin_chunk, host arrays, uploads
+#:       engine.chunk_dispatch         the jitted chunk call
+#:       engine.first_token            _finish_prefill's sample
+#:     engine.prepare_writes
+#:     engine.decode_batch
+#:       engine.decode_inputs          token array + kvc.batch_inputs()
+#:       engine.decode_dispatch        the jitted decode call
+#:       engine.sample                 the per-slot loop, kvc.advance
+#:         engine.sample_dispatch      the slot's logits row, argmax
+#:       engine.finish                 _finish / release of done slots
+#:     engine.fetch                    every blocking device-to-host read
+STEP_SPANS = (
+    "engine.step", "engine.admit", "engine.prefetch",
+    "engine.prefill_chunk", "engine.chunk_inputs",
+    "engine.chunk_dispatch", "engine.first_token",
+    "engine.prepare_writes", "engine.decode_batch",
+    "engine.decode_inputs", "engine.decode_dispatch", "engine.sample",
+    "engine.finish", "engine.fetch", "engine.sample_dispatch")
+
 
 class _EngineBase:
     """Queue intake, bucketed prefill, sampling, and the run loop."""
@@ -105,6 +132,11 @@ class _EngineBase:
         self.completions: List[Completion] = []
         self._futures: Dict[int, Future] = {}
         self._prefills: Dict[int, Any] = {}
+        # this step's blocking device-to-host reads (`_fetch`) and the
+        # host clock at its start, for the counter row's host times
+        self._syncs = 0
+        self._fetch_s = 0.0
+        self._step_t0 = time.perf_counter()
 
     # -- observability ------------------------------------------------
     def set_tracer(self, tracer) -> None:
@@ -219,15 +251,35 @@ class _EngineBase:
             self._prefills[bucket] = jax.jit(fn)
         return self._prefills[bucket]
 
+    def _fetch(self, x) -> np.ndarray:
+        """Every blocking device-to-host read of the engines: one
+        `engine.fetch` span, counted and timed for the step's
+        `host_syncs` and `fetch_ms`."""
+        with self.trace.span("engine", "fetch", kind="copy"):
+            t = time.perf_counter()
+            out = np.asarray(x)
+            self._fetch_s += time.perf_counter() - t
+        self._syncs += 1
+        return out
+
+    def _step_telemetry(self) -> dict:
+        """A counter row's host-time keys: the step's fetches, the host
+        time blocked in them, and the step's host wall time so far."""
+        return {"host_syncs": self._syncs,
+                "fetch_ms": self._fetch_s * 1e3,
+                "step_ms": (time.perf_counter() - self._step_t0) * 1e3}
+
     def _sample(self, logits: jnp.ndarray, req: Request,
                 n_gen: int) -> int:
         """Sample keyed by (rid, generated-token count) — each step of
         each request gets a distinct PRNG key."""
-        if req.temperature <= 0:
-            return int(jnp.argmax(logits))
-        key = jax.random.PRNGKey(req.rid * 7919 + n_gen)
-        return int(jax.random.categorical(key,
-                                          logits / req.temperature))
+        with self.trace.span("engine", "sample_dispatch", kind="compute"):
+            if req.temperature <= 0:
+                tok = jnp.argmax(logits)
+            else:
+                key = jax.random.PRNGKey(req.rid * 7919 + n_gen)
+                tok = jax.random.categorical(key, logits / req.temperature)
+        return int(self._fetch(tok))
 
     def _reject(self, item: dict, err: Exception) -> None:
         """Fail one request without killing the engine: its completion
@@ -335,10 +387,10 @@ class _EngineBase:
 
     def step(self) -> int:
         """One scheduling step.  The root span of the per-step trace
-        tree: overhead attribution decomposes its wall-clock into the
-        child spans' kinds (obs/attribution.py)."""
-        if not self.trace.enabled:
-            return self._step()
+        tree (STEP_SPANS): overhead attribution decomposes its
+        wall-clock into the child spans' kinds (obs/attribution.py)."""
+        self._syncs, self._fetch_s = 0, 0.0
+        self._step_t0 = time.perf_counter()
         with self.trace.span("engine", "step") as sp:
             n = self._step()
             sp.args["ran"] = n
@@ -596,7 +648,13 @@ class PagedServingEngine(_EngineBase):
         self.recovery_budget = RecoveryBudget()
         self.re_prefills = 0     # requests that lost KV and re-prefilled
         self.drained_slots = 0   # active slots drained by a kill
-        self.counters: List[dict] = []         # per-step telemetry
+        # per-step telemetry, one row per step that ran a batch: queue
+        # and slot counts, pages, and host times — `decode_ms` is host
+        # time from the decode batch's start to the row, sampling
+        # included, not device time; `host_syncs` counts the step's
+        # blocking device-to-host reads, `fetch_ms` the host time
+        # blocked in them, `step_ms` the step's host wall time
+        self.counters: List[dict] = []
         # prefix-cache compute skip (DESIGN.md §4e)
         self._prefix_skip = bool(prefix_cache_compute)
         self.prefix_skips = 0            # fully-covered admissions
@@ -787,8 +845,8 @@ class PagedServingEngine(_EngineBase):
                             pcache["v"][:, 0, :real])
             if self._prefix_skip:
                 self.kvc.store_hidden_prefill(slot, real,
-                                              np.asarray(bh[0]),
-                                              np.asarray(hlast[0]))
+                                              self._fetch(bh[0]),
+                                              self._fetch(hlast[0]))
             first = self._sample(logits[0], req, len(item["gen"]))
             now = time.perf_counter()
             self.active[slot] = {
@@ -1131,38 +1189,40 @@ class PagedServingEngine(_EngineBase):
         EOS or their length cap.  Returns the finished slots.  Shared
         by the whole-prompt and chunked engines, so sampling and
         completion bookkeeping can never diverge between them."""
-        if not self.trace.enabled:
-            return self._decode_batch_impl(slots)
-        with self.trace.span("engine", "decode_batch", kind="compute",
-                             n=len(slots)) as sp:
-            done = self._decode_batch_impl(slots)
+        span = self.trace.span
+        with span("engine", "decode_batch", kind="compute",
+                  n=len(slots)) as sp:
+            with span("engine", "decode_inputs", kind="copy"):
+                tokens = np.zeros((self.slots, 1), np.int32)
+                for slot in slots:
+                    tokens[slot, 0] = self.active[slot]["tokens"][-1]
+                batch = {"tokens": jnp.asarray(tokens),
+                         **self.kvc.batch_inputs()}
+            with span("engine", "decode_dispatch", kind="compute"):
+                logits, pages = self._decode(self.params,
+                                             self.kvc.pool.pages, batch)
+                self.kvc.pool.pages = pages
+            done: List[int] = []
+            now = time.perf_counter()
+            with span("engine", "sample", kind="compute"):
+                for slot in slots:
+                    st = self.active[slot]
+                    self.kvc.advance(slot)
+                    req = st["req"]
+                    with span("engine", "sample_dispatch",
+                              kind="compute"):
+                        row = logits[slot]
+                    tok = self._sample(row, req, len(st["tokens"]))
+                    st["tokens"].append(tok)
+                    st["tok_t"].append(now)
+                    if self._stopped(req, st["tokens"]):
+                        done.append(slot)
+            with span("engine", "finish", kind="sched"):
+                for slot in done:
+                    self._finish(self.active.pop(slot))
+                    self.kvc.release(slot)
+                    self.free_slots.append(slot)
             sp.args["finished"] = len(done)
-        return done
-
-    def _decode_batch_impl(self, slots: List[int]) -> List[int]:
-        tokens = np.zeros((self.slots, 1), np.int32)
-        for slot in slots:
-            tokens[slot, 0] = self.active[slot]["tokens"][-1]
-        batch = {"tokens": jnp.asarray(tokens),
-                 **self.kvc.batch_inputs()}
-        logits, pages = self._decode(self.params, self.kvc.pool.pages,
-                                     batch)
-        self.kvc.pool.pages = pages
-        done: List[int] = []
-        now = time.perf_counter()
-        for slot in slots:
-            st = self.active[slot]
-            self.kvc.advance(slot)
-            req = st["req"]
-            tok = self._sample(logits[slot], req, len(st["tokens"]))
-            st["tokens"].append(tok)
-            st["tok_t"].append(now)
-            if self._stopped(req, st["tokens"]):
-                done.append(slot)
-        for slot in done:
-            self._finish(self.active.pop(slot))
-            self.kvc.release(slot)
-            self.free_slots.append(slot)
         return done
 
     def _offloaded_queued(self) -> int:
@@ -1209,6 +1269,7 @@ class PagedServingEngine(_EngineBase):
             "page_occupancy": pool.occupancy(),
             "preemptions": self.preemptions,
             "decode_ms": (time.perf_counter() - t0) * 1e3,
+            **self._step_telemetry(),
         })
         self._record_step_metrics(self.counters[-1])
         return len(self.active) + len(done)
@@ -1514,22 +1575,15 @@ class ChunkedPagedServingEngine(PagedServingEngine):
         Returns False if the slot was preempted (or rejected) by page
         exhaustion instead of advanced."""
         rec = self.recorder.enabled
-        if not self.trace.enabled and not rec:
-            return self._run_chunk_impl(slot, take)
         st = self.active[slot]
         rid = st["req"].rid
         start = st["pos"]
         tr = time.perf_counter() if rec else 0.0
-        if not self.trace.enabled:
+        with self.trace.span("engine", "prefill_chunk", kind="compute",
+                             rid=rid, slot=slot, start=start, take=take,
+                             loc=self._chunk_locality(slot, st)) as sp:
             ok = self._run_chunk_impl(slot, take)
-        else:
-            with self.trace.span("engine", "prefill_chunk",
-                                 kind="compute", rid=rid, slot=slot,
-                                 start=start, take=take,
-                                 loc=self._chunk_locality(slot, st)) \
-                    as sp:
-                ok = self._run_chunk_impl(slot, take)
-                sp.args["ran"] = ok
+            sp.args["ran"] = ok
         if rec:
             self.recorder.event(rid, "prefill_chunk", start=start,
                                 take=take, ran=ok,
@@ -1540,55 +1594,60 @@ class ChunkedPagedServingEngine(PagedServingEngine):
         st = self.active[slot]
         start = st["pos"]
         end = start + take
-        while True:
-            try:
-                rows, _ = self.kvc.begin_chunk(
-                    slot, st["layout"], start, end,
-                    locality=self._chunk_locality(slot, st))
-                break
-            except PageExhausted:
-                if len(self.active) <= 1:
-                    self.active.pop(slot)
-                    self.kvc.release(slot)
-                    self.free_slots.append(slot)
-                    self._reject({"req": st["req"]}, RuntimeError(
-                        "page pool too small for request "
-                        f"{st['req'].rid}: {self.kvc.pool.capacity} "
-                        f"pages of {self.kvc.pool.page_size}"))
-                    return False
-                victim = max(self.active,
-                             key=lambda s: self.active[s]["seq"])
-                self._preempt(victim)
-                if victim == slot:
-                    return False
-        ps = self.kvc.pool.page_size
-        t0 = time.perf_counter()
-        toks = np.zeros(self.chunk_size, np.int32)
-        toks[:take] = st["layout"][start:end]
-        rows_arr = np.full(self.chunk_size // ps,
-                           self.kvc.pool.null_row, np.int32)
-        rows_arr[:len(rows)] = rows
-        # a host copy: the table mutates in place while its transfer
-        # may still read it (PagedKVCache.batch_inputs)
-        table = jnp.asarray(self.kvc.tables[slot][None].copy())
-        logits, hlast, bh, pages = self._chunk_step(
-            self.params, self.kvc.pool.pages,
-            jnp.asarray(toks[None]), table,
-            jnp.asarray([start], jnp.int32),
-            jnp.asarray(rows_arr[None]),
-            jnp.int32(take - 1))
-        self.kvc.pool.pages = pages
+        with self.trace.span("engine", "chunk_inputs", kind="copy"):
+            while True:
+                try:
+                    rows, _ = self.kvc.begin_chunk(
+                        slot, st["layout"], start, end,
+                        locality=self._chunk_locality(slot, st))
+                    break
+                except PageExhausted:
+                    if len(self.active) <= 1:
+                        self.active.pop(slot)
+                        self.kvc.release(slot)
+                        self.free_slots.append(slot)
+                        self._reject({"req": st["req"]}, RuntimeError(
+                            "page pool too small for request "
+                            f"{st['req'].rid}: "
+                            f"{self.kvc.pool.capacity} pages of "
+                            f"{self.kvc.pool.page_size}"))
+                        return False
+                    victim = max(self.active,
+                                 key=lambda s: self.active[s]["seq"])
+                    self._preempt(victim)
+                    if victim == slot:
+                        return False
+            ps = self.kvc.pool.page_size
+            t0 = time.perf_counter()
+            toks = np.zeros(self.chunk_size, np.int32)
+            toks[:take] = st["layout"][start:end]
+            rows_arr = np.full(self.chunk_size // ps,
+                               self.kvc.pool.null_row, np.int32)
+            rows_arr[:len(rows)] = rows
+            # a host copy: the table mutates in place while its
+            # transfer may still read it (PagedKVCache.batch_inputs)
+            inputs = (jnp.asarray(toks[None]),
+                      jnp.asarray(self.kvc.tables[slot][None].copy()),
+                      jnp.asarray([start], jnp.int32),
+                      jnp.asarray(rows_arr[None]),
+                      jnp.int32(take - 1))
+        with self.trace.span("engine", "chunk_dispatch", kind="compute"):
+            logits, hlast, bh, pages = self._chunk_step(
+                self.params, self.kvc.pool.pages, *inputs)
+            self.kvc.pool.pages = pages
         if self._prefix_skip:
             # checkpoint the chunk's page-boundary activations into
             # the prefix index (one small host copy) — later identical
             # prefixes resume from them instead of recomputing (§4e)
             self.kvc.store_hidden_chunk(slot, start, end,
-                                        np.asarray(bh[0]),
-                                        np.asarray(hlast[0]))
+                                        self._fetch(bh[0]),
+                                        self._fetch(hlast[0]))
         st["pos"] = end
         st["prefill_s"] += time.perf_counter() - t0
         if end == st["real"]:
-            self._finish_prefill(slot, st, logits)
+            with self.trace.span("engine", "first_token",
+                                 kind="compute"):
+                self._finish_prefill(slot, st, logits)
         return True
 
     def _finish_prefill(self, slot: int, st: dict, logits) -> None:
@@ -1652,6 +1711,7 @@ class ChunkedPagedServingEngine(PagedServingEngine):
             "prefill_chunk_tokens": prefill_tok,
             "decode_tokens": len(decoding),
             "budget_tokens": self.step_tokens,
+            **self._step_telemetry(),
         })
         self._record_step_metrics(self.counters[-1])
         return len(self.active) + len(done)
