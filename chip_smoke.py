@@ -1,6 +1,6 @@
 """Drive the serving main path once on a TPU, at yi-6b's published widths.
 
-    python chip_smoke.py             # one chip: serve, logits, kernels
+    python chip_smoke.py             # one chip: serve, tokens, kernels
     python chip_smoke.py --chips 4   # four chips: sharded pool on a mesh
 
 One process runs every phase; no phase catches an exception, so a
@@ -13,10 +13,10 @@ Phases on one chip:
   through make_engine -> ChunkedPagedServingEngine -> PagedKVCache, at
   every published width and all 32 layers in bf16, with a page pool
   sized from the HBM left after the weights.  Every completion future
-  must resolve with its tokens, every logit vector the engine sampled
-  from must be finite, and request 0's first-token logits must agree
-  with the plain un-paged `T.forward` / `T.logits_fn` of the same
-  weights.
+  must resolve with its tokens, and every served token must be the
+  best, or within a rounding gap of it, under the plain un-paged
+  `T.forward` / `T.logits_fn` of the same weights over the prompt and
+  the tokens served before it.
 * kernels — the paged decode and prefill Pallas kernels, on flat and
   locality-sharded pools, compiled (interpret=False) and compared with
   the jnp oracles of kernels/attention/ref.py at yi-6b's head widths.
@@ -24,7 +24,8 @@ Phases on one chip:
 With ``--chips 4`` only the mesh phase runs: the engine with
 ``kv_shards=4`` over a real four-device `kv_pool_mesh`, with one forced
 page migration (the shard_map / ppermute legs), against
-``kv_shards=1`` on the same prompts, compared on decode logits.
+``kv_shards=1`` on the same prompts, both runs' tokens held to the
+un-paged forward as in the serve phase.
 
 The last line of standard output is
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
@@ -75,6 +76,12 @@ RESERVE_BYTES = 3 << 29                               # 1.5 GiB
 # 0.018-0.019.  A causal mask off by one position moved it to 0.39-0.64
 # in the same run, so the check sees a wrong page or position.
 LOGIT_REL_TOL = 0.05
+# The engine samples on the device and returns tokens only, so the
+# check is on them: a served greedy token is the best of logits within
+# LOGIT_REL_TOL of the reference's, so its reference logit lies at most
+# twice that below the reference's best (relative to the row's largest
+# |logit|).
+TOKEN_GAP_TOL = 2 * LOGIT_REL_TOL
 # Kernel vs oracle, same bf16 inputs: both take a convex combination
 # of bf16 value rows, and differ only in where the probabilities and
 # the output are rounded to bf16 (8 significant bits), so the outputs
@@ -101,17 +108,29 @@ def rel_diff(got, want, scale=None) -> float:
     return amax(diff) / (amax(want) if scale is None else scale)
 
 
-def record_logits(engine) -> dict:
-    """Keep every logit vector the engine samples from, keyed by
-    (rid, tokens generated before it)."""
-    seen = {}
-    sample = engine._sample
-
-    def recording(logits, req, n_gen):
-        seen[(req.rid, n_gen)] = logits
-        return sample(logits, req, n_gen)
-    engine._sample = recording
-    return seen
+def token_gaps(params, cfg, prompts, served) -> list:
+    """For each request, the largest gap by which a served token's
+    logit lies below the best logit of the un-paged forward over the
+    prompt and the tokens served before it, relative to the row's
+    largest |logit|.  Every sequence is right-padded to one length
+    (causal: the pad never reaches earlier positions), so the forward
+    compiles once."""
+    n = max(len(p) + len(t) for p, t in zip(prompts, served))
+    seq = np.zeros(-(-n // PAGE_SIZE) * PAGE_SIZE, np.int32)
+    forward = jax.jit(lambda p, toks: T.logits_fn(
+        p, T.forward(p, {"tokens": toks}, cfg)[0][0]))
+    gaps = []
+    for prompt, toks in zip(prompts, served):
+        full = np.concatenate([prompt, np.asarray(toks, np.int32)])
+        seq[:] = 0
+        seq[:len(full)] = full
+        lg = np.asarray(forward(params, jnp.asarray(seq[None])),
+                        np.float32)
+        rows = lg[len(prompt) - 1:len(full) - 1]
+        got = rows[np.arange(len(toks)), toks]
+        gaps.append(float(np.max((rows.max(-1) - got)
+                                 / np.abs(rows).max(-1))))
+    return gaps
 
 
 def make_prompts(cfg, seed: int, n: int, lo: int, hi: int):
@@ -159,13 +178,11 @@ def phase_serve(cfg, *, n_requests: int = 8,
                       n_pages=n_pages)
     log(f"page pool: {n_pages} pages of {PAGE_SIZE} tokens "
         f"({n_pages * PAGE_SIZE} tokens of KV)")
-    seen = record_logits(eng)
-    # one short request compiles the chunk step, the decode step and
-    # the sampler; the timed serve below then compiles nothing
+    # one short request compiles the chunk step and the decode step,
+    # each with its sampler; the timed serve below then compiles nothing
     t = time.perf_counter()
     serve(eng, make_prompts(cfg, SEED + 1, 1, 16, 16), 2)
     compile_s = time.perf_counter() - t
-    seen.clear()
     prompts = make_prompts(cfg, SEED, n_requests, *prompt_lens)
     t = time.perf_counter()
     comps = serve(eng, prompts, new_tokens)
@@ -175,23 +192,13 @@ def phase_serve(cfg, *, n_requests: int = 8,
         f"{len(comps)} requests, prompts {min(map(len, prompts))}-"
         f"{max(map(len, prompts))} tokens, {n_tok} new tokens in "
         f"{serve_s:.2f}s")
-    missing = [(r, n) for r in range(len(prompts))
-               for n in range(new_tokens) if (r, n) not in seen]
-    check(not missing, f"no sampled logits for {missing[:4]}")
-    bad = [k for k, v in seen.items() if not bool(jnp.isfinite(v).all())]
-    check(not bad, f"non-finite logits at {bad[:4]}")
-    log(f"{len(seen)} sampled logit vectors, all finite")
-
-    forward = jax.jit(lambda p, toks: T.logits_fn(
-        p, T.forward(p, {"tokens": toks}, cfg)[0][:, -1]))
-    want = forward(params, jnp.asarray(prompts[0][None]))[0]
-    diff = rel_diff(seen[(0, 0)], want)
-    log(f"first-token logits, served vs un-paged forward "
-        f"({len(prompts[0])}-token prompt): max|diff|/max|ref| = "
-        f"{diff:.3e} (tolerance {LOGIT_REL_TOL})")
-    check(diff <= LOGIT_REL_TOL, "served logits disagree with the forward")
+    gap = max(token_gaps(params, cfg, prompts, [c.tokens for c in comps]))
+    log(f"served tokens vs un-paged forward ({n_tok} tokens): largest "
+        f"gap below the best logit / max|ref| = {gap:.3e} (tolerance "
+        f"{TOKEN_GAP_TOL})")
+    check(gap <= TOKEN_GAP_TOL, "served tokens disagree with the forward")
     return {"compile_s": compile_s, "serve_s": serve_s, "tokens": n_tok,
-            "logit_rel_diff": diff, "pages": n_pages}
+            "token_gap": gap, "pages": n_pages}
 
 
 def phase_kernels(cfg, *, interpret: bool, batch: int = 8,
@@ -247,7 +254,6 @@ def phase_mesh(cfg, *, n_shards: int = 4, n_requests: int = 4,
         f"{n_shards} over mesh {dict(mesh.shape)}")
 
     base = make_engine(params, cfg, **kw)
-    seen1 = record_logits(base)
     t = time.perf_counter()
     ref = {c.rid: c.tokens for c in serve(base, prompts, new_tokens)}
     log(f"kv_shards=1: {time.perf_counter() - t:.2f}s")
@@ -259,7 +265,6 @@ def phase_mesh(cfg, *, n_shards: int = 4, n_requests: int = 4,
     check(sharding.spec[1] == "kv"
           and len(sharding.device_set) == n_shards,
           f"page pool not sharded over the mesh: {sharding}")
-    seen4 = record_logits(eng)
     t = time.perf_counter()
     futs = [eng.submit(Request(rid, p, max_new_tokens=new_tokens))
             for rid, p in enumerate(prompts)]
@@ -271,18 +276,18 @@ def phase_mesh(cfg, *, n_shards: int = 4, n_requests: int = 4,
     got = {c.rid: c.tokens for c in (f.get() for f in futs)}
     log(f"kv_shards={n_shards}: {time.perf_counter() - t:.2f}s, "
         f"{moved} pages migrated between devices")
-    # decode logits are comparable while both runs fed the same tokens
-    diffs = [rel_diff(seen4[(rid, n)], seen1[(rid, n)])
-             for rid, toks in ref.items() for n in range(1, new_tokens)
-             if got[rid][:n] == toks[:n]]
+    gaps = token_gaps(params, cfg, prompts * 2,
+                      [ref[r] for r in range(n_requests)]
+                      + [got[r] for r in range(n_requests)])
     same = sum(got[r] == ref[r] for r in ref)
-    log(f"decode logits, {n_shards} shards vs 1: {len(diffs)} vectors "
-        f"compared, max|diff|/max|ref| = {max(diffs):.3e} (tolerance "
-        f"{LOGIT_REL_TOL}); {same}/{len(ref)} requests token-identical")
-    check(len(diffs) >= n_requests and max(diffs) <= LOGIT_REL_TOL,
-          "sharded decode logits disagree with one shard")
-    return {"moved": moved, "logit_rel_diff": max(diffs),
-            "compared": len(diffs), "identical": same}
+    log(f"served tokens vs un-paged forward, 1 shard and {n_shards}: "
+        f"{len(gaps)} requests compared, largest gap below the best "
+        f"logit / max|ref| = {max(gaps):.3e} (tolerance "
+        f"{TOKEN_GAP_TOL}); {same}/{len(ref)} requests token-identical")
+    check(len(gaps) == 2 * n_requests and max(gaps) <= TOKEN_GAP_TOL,
+          "sharded or one-shard tokens disagree with the forward")
+    return {"moved": moved, "token_gap": max(gaps),
+            "compared": len(gaps), "identical": same}
 
 
 def main(argv=None) -> None:

@@ -71,6 +71,8 @@ from repro.obs.slo import FlightRecorder, NULL_RECORDER, classify, \
 from repro.obs.trace import NULL_TRACER
 from repro.serving.kvcache import (PagedKVCache, PageExhausted,
                                    PAGED_FAMILIES, page_keys)
+from repro.serving.sampling import sample_rows, sample_step, \
+    sample_tokens, sampler_inputs
 # Request/Completion moved to serving/types.py (the worker split);
 # re-exported here because tests/benchmarks import them from engine
 from repro.serving.types import Completion, Request, _mean, _pct  # noqa: F401
@@ -80,21 +82,19 @@ from repro.serving.workers import (DecodeWorker, HandoffDecodeWorker,
 
 #: The serving step's span tree, by profiler annotation name
 #: (obs/trace.py).  `engine.fetch` nests wherever a blocking
-#: device-to-host read happens, `engine.sample_dispatch` wherever the
-#: sampler dispatches its eager device ops:
+#: device-to-host read happens:
 #:
 #:   engine.step
 #:     engine.admit  engine.prefetch
 #:     engine.prefill_chunk            (per chunk)
 #:       engine.chunk_inputs           begin_chunk, host arrays, uploads
 #:       engine.chunk_dispatch         the jitted chunk call
-#:       engine.first_token            _finish_prefill's sample
+#:       engine.first_token            _finish_prefill's token read
 #:     engine.prepare_writes
 #:     engine.decode_batch
-#:       engine.decode_inputs          token array + kvc.batch_inputs()
+#:       engine.decode_inputs          token, sampler and kvc.batch_inputs()
 #:       engine.decode_dispatch        the jitted decode call
-#:       engine.sample                 the per-slot loop, kvc.advance
-#:         engine.sample_dispatch      the slot's logits row, argmax
+#:       engine.sample                 the step's token read, kvc.advance
 #:       engine.finish                 _finish / release of done slots
 #:     engine.fetch                    every blocking device-to-host read
 STEP_SPANS = (
@@ -103,7 +103,7 @@ STEP_SPANS = (
     "engine.chunk_dispatch", "engine.first_token",
     "engine.prepare_writes", "engine.decode_batch",
     "engine.decode_inputs", "engine.decode_dispatch", "engine.sample",
-    "engine.finish", "engine.fetch", "engine.sample_dispatch")
+    "engine.finish", "engine.fetch")
 
 
 class _EngineBase:
@@ -271,15 +271,12 @@ class _EngineBase:
 
     def _sample(self, logits: jnp.ndarray, req: Request,
                 n_gen: int) -> int:
-        """Sample keyed by (rid, generated-token count) — each step of
-        each request gets a distinct PRNG key."""
-        with self.trace.span("engine", "sample_dispatch", kind="compute"):
-            if req.temperature <= 0:
-                tok = jnp.argmax(logits)
-            else:
-                key = jax.random.PRNGKey(req.rid * 7919 + n_gen)
-                tok = jax.random.categorical(key, logits / req.temperature)
-        return int(self._fetch(tok))
+        """Sample a prefill's (1, V) logits with the programs' device
+        sampler (serving/sampling.py), keyed by (rid, generated-token
+        count): one dispatch, one read."""
+        s = sampler_inputs([(0, req, n_gen)], 1)
+        tok = sample_rows(logits, s["temperature"], s["key_data"])
+        return int(self._fetch(tok)[0])
 
     def _reject(self, item: dict, err: Exception) -> None:
         """Fail one request without killing the engine: its completion
@@ -454,7 +451,7 @@ class DenseServingEngine(_EngineBase):
         # one shared batched cache across slots
         self.cache = T.init_cache(cfg, slots, max_len)
         self._decode = jax.jit(
-            lambda p, c, b: T.decode_step(p, c, b, cfg))
+            lambda p, c, b: sample_step(T.decode_step, p, c, b, cfg))
 
     def _admit(self) -> None:
         while self.queue and self.free_slots:
@@ -480,7 +477,7 @@ class DenseServingEngine(_EngineBase):
                                     dur=time.perf_counter() - t0)
             # splice this request's prefill cache into the slot pool
             self._splice_cache(slot, pcache, bucket)
-            first = self._sample(logits[0], req, len(item["gen"]))
+            first = self._sample(logits, req, len(item["gen"]))
             now = time.perf_counter()
             self.active[slot] = {
                 "req": req, "tokens": item["gen"] + [int(first)],
@@ -539,7 +536,9 @@ class DenseServingEngine(_EngineBase):
         tokens = np.zeros((self.slots, 1), np.int32)
         for slot, st in self.active.items():
             tokens[slot, 0] = st["tokens"][-1]
-        batch = {"tokens": jnp.asarray(tokens)}
+        batch = {"tokens": jnp.asarray(tokens), **sampler_inputs(
+            ((slot, st["req"], len(st["tokens"]))
+             for slot, st in self.active.items()), self.slots)}
         if self.cfg.family == "vlm":
             batch["patch_embeds"] = jnp.zeros(
                 (self.slots, self.cfg.n_frontend_tokens,
@@ -547,14 +546,14 @@ class DenseServingEngine(_EngineBase):
                 jnp.dtype(self.cfg.dtype))
         with self.trace.span("engine", "decode_batch", kind="compute",
                              n=len(self.active)):
-            logits, self.cache = self._decode(self.params, self.cache,
-                                              batch)
+            next_tokens, self.cache = self._decode(self.params,
+                                                   self.cache, batch)
         done = []
         now = time.perf_counter()
+        toks = self._fetch(next_tokens)
         for slot, st in self.active.items():
             req = st["req"]
-            tok = self._sample(logits[slot], req, len(st["tokens"]))
-            st["tokens"].append(tok)
+            st["tokens"].append(int(toks[slot]))
             st["tok_t"].append(now)
             if self._stopped(req, st["tokens"]):
                 done.append(slot)
@@ -635,7 +634,8 @@ class PagedServingEngine(_EngineBase):
         # donate the page pool: on accelerators the step updates KV
         # pages in place instead of holding input + output copies
         self._decode = jax.jit(
-            lambda p, pages, b: T.decode_step_paged(p, pages, b, cfg),
+            lambda p, pages, b: sample_step(T.decode_step_paged, p,
+                                            pages, b, cfg),
             donate_argnums=(1,))
         self._seq = itertools.count()          # admission order
         self.preemptions = 0
@@ -720,7 +720,7 @@ class PagedServingEngine(_EngineBase):
         if self.recorder.enabled:
             self.recorder.event(req.rid, "resume",
                                 dur=time.perf_counter() - tr)
-        first = self._sample(logits[0], req, len(item["gen"]))
+        first = self._sample(logits, req, len(item["gen"]))
         now = time.perf_counter()
         self.prefix_skips += 1
         self.prefill_tokens_skipped += real
@@ -847,7 +847,7 @@ class PagedServingEngine(_EngineBase):
                 self.kvc.store_hidden_prefill(slot, real,
                                               self._fetch(bh[0]),
                                               self._fetch(hlast[0]))
-            first = self._sample(logits[0], req, len(item["gen"]))
+            first = self._sample(logits, req, len(item["gen"]))
             now = time.perf_counter()
             self.active[slot] = {
                 "req": req, "tokens": item["gen"] + [int(first)],
@@ -1185,10 +1185,12 @@ class PagedServingEngine(_EngineBase):
     # -- the decode work-queue ----------------------------------------
     def _decode_batch(self, slots: List[int]) -> List[int]:
         """One compiled decode step for `slots`: assemble the batch,
-        sample each slot's next token, finish/release requests that hit
-        EOS or their length cap.  Returns the finished slots.  Shared
-        by the whole-prompt and chunked engines, so sampling and
-        completion bookkeeping can never diverge between them."""
+        run the decode program, which samples every slot's next token
+        on the device, read the tokens back in one fetch, and
+        finish/release requests that hit EOS or their length cap.
+        Returns the finished slots.  Shared by the whole-prompt and
+        chunked engines, so sampling and completion bookkeeping can
+        never diverge between them."""
         span = self.trace.span
         with span("engine", "decode_batch", kind="compute",
                   n=len(slots)) as sp:
@@ -1197,23 +1199,24 @@ class PagedServingEngine(_EngineBase):
                 for slot in slots:
                     tokens[slot, 0] = self.active[slot]["tokens"][-1]
                 batch = {"tokens": jnp.asarray(tokens),
+                         **sampler_inputs(
+                             ((slot, self.active[slot]["req"],
+                               len(self.active[slot]["tokens"]))
+                              for slot in slots), self.slots),
                          **self.kvc.batch_inputs()}
             with span("engine", "decode_dispatch", kind="compute"):
-                logits, pages = self._decode(self.params,
-                                             self.kvc.pool.pages, batch)
+                next_tokens, pages = self._decode(
+                    self.params, self.kvc.pool.pages, batch)
                 self.kvc.pool.pages = pages
             done: List[int] = []
             now = time.perf_counter()
             with span("engine", "sample", kind="compute"):
+                toks = self._fetch(next_tokens)
                 for slot in slots:
                     st = self.active[slot]
                     self.kvc.advance(slot)
                     req = st["req"]
-                    with span("engine", "sample_dispatch",
-                              kind="compute"):
-                        row = logits[slot]
-                    tok = self._sample(row, req, len(st["tokens"]))
-                    st["tokens"].append(tok)
+                    st["tokens"].append(int(toks[slot]))
                     st["tok_t"].append(now)
                     if self._stopped(req, st["tokens"]):
                         done.append(slot)
@@ -1438,20 +1441,25 @@ class ChunkedPagedServingEngine(PagedServingEngine):
                 f"step_tokens {self.step_tokens} must cover at least "
                 f"one chunk of {self.chunk_size}")
         # ONE compiled chunk step (fixed chunk width; the true last
-        # position and start offset are traced operands).  Besides the
-        # logits it returns the post-norm hidden at the true last
-        # position and at every page boundary — the activation
-        # checkpoints the prefix index stores for compute skip (§4e)
+        # position and start offset are traced operands).  It samples
+        # the token after the true last position on the device (a
+        # prompt's first token, read on its final chunk) and returns
+        # the post-norm hidden there and at every page boundary — the
+        # activation checkpoints the prefix index stores for compute
+        # skip (§4e)
         ps = page_size
 
-        def chunk_fn(p, pages, toks, tables, start, rows, last):
+        def chunk_fn(p, pages, toks, tables, start, rows, last,
+                     temperature, key_data):
             x, pages = T.prefill_chunk(p, pages, {
                 "tokens": toks, "block_tables": tables, "start": start,
                 "chunk_rows": rows, "last_index": last}, cfg,
                 all_hidden=True)
             out = jax.lax.dynamic_index_in_dim(x, last, axis=1,
                                                keepdims=False)
-            return T.logits_fn(p, out), out, x[:, ps - 1::ps], pages
+            tok = sample_tokens(T.logits_fn(p, out), temperature,
+                                key_data)
+            return tok, out, x[:, ps - 1::ps], pages
         self._chunk_step = jax.jit(chunk_fn, donate_argnums=(1,))
         # the role composition (DESIGN.md §4f): a role-agnostic token-
         # budget scheduler drives a prefill role and a decode role.
@@ -1624,15 +1632,21 @@ class ChunkedPagedServingEngine(PagedServingEngine):
             rows_arr = np.full(self.chunk_size // ps,
                                self.kvc.pool.null_row, np.int32)
             rows_arr[:len(rows)] = rows
+            # only a prompt's final chunk samples: earlier ones run at
+            # temperature 0 and their token is never read
+            final = [(0, st["req"], st["n_gen0"])] \
+                if end == st["real"] else []
+            s = sampler_inputs(final, 1)
             # a host copy: the table mutates in place while its
             # transfer may still read it (PagedKVCache.batch_inputs)
             inputs = (jnp.asarray(toks[None]),
                       jnp.asarray(self.kvc.tables[slot][None].copy()),
                       jnp.asarray([start], jnp.int32),
                       jnp.asarray(rows_arr[None]),
-                      jnp.int32(take - 1))
+                      jnp.int32(take - 1),
+                      s["temperature"], s["key_data"])
         with self.trace.span("engine", "chunk_dispatch", kind="compute"):
-            logits, hlast, bh, pages = self._chunk_step(
+            tok, hlast, bh, pages = self._chunk_step(
                 self.params, self.kvc.pool.pages, *inputs)
             self.kvc.pool.pages = pages
         if self._prefix_skip:
@@ -1647,20 +1661,19 @@ class ChunkedPagedServingEngine(PagedServingEngine):
         if end == st["real"]:
             with self.trace.span("engine", "first_token",
                                  kind="compute"):
-                self._finish_prefill(slot, st, logits)
+                self._finish_prefill(slot, st, tok)
         return True
 
-    def _finish_prefill(self, slot: int, st: dict, logits) -> None:
-        """Final chunk landed: the prompt is resident — sample the
-        first token and hand the slot to the decode batch.  The
-        disaggregated engine overrides this seam to stage the
-        prefill->decode KV handoff instead of flipping the phase in
+    def _finish_prefill(self, slot: int, st: dict, tok) -> None:
+        """Final chunk landed: the prompt is resident — read the first
+        token the chunk program sampled and hand the slot to the decode
+        batch.  The disaggregated engine overrides this seam to stage
+        the prefill->decode KV handoff instead of flipping the phase in
         place (DESIGN.md §4f)."""
         now = time.perf_counter()
         st["phase"] = "decode"
         st["t0"] = now
-        first = self._sample(logits[0], st["req"], st["n_gen0"])
-        st["tokens"].append(int(first))
+        st["tokens"].append(int(self._fetch(tok)[0]))
         self._first_token(st, now)
         if self._stopped(st["req"], st["tokens"]):
             self._finish(self.active.pop(slot))
@@ -1830,15 +1843,14 @@ class DisaggChunkedServingEngine(ChunkedPagedServingEngine):
         return st.get("ploc")
 
     # -- the prefill->decode handoff ----------------------------------
-    def _finish_prefill(self, slot: int, st: dict, logits) -> None:
-        """Final chunk landed at the prefill worker: sample the first
-        token THERE (the logits die with the chunk), then stage the
-        KV handoff to the decode role instead of flipping the phase in
-        place."""
+    def _finish_prefill(self, slot: int, st: dict, tok) -> None:
+        """Final chunk landed at the prefill worker: read the first
+        token its chunk program sampled THERE (the logits die with the
+        chunk), then stage the KV handoff to the decode role instead of
+        flipping the phase in place."""
         now = time.perf_counter()
         st["t0"] = now
-        first = self._sample(logits[0], st["req"], st["n_gen0"])
-        st["tokens"].append(int(first))
+        st["tokens"].append(int(self._fetch(tok)[0]))
         self._first_token(st, now)
         if self._stopped(st["req"], st["tokens"]):
             self._finish(self.active.pop(slot))

@@ -199,7 +199,7 @@ class StepScheduler:
                 n_chunks += 1
         prefill.flush(eng)
         # the decode batch: prefilling slots ride along masked (their
-        # write row is the null page; their logits are discarded)
+        # write row is the null page; their tokens are discarded)
         done: List[int] = []
         decoding = [s for s in decoding if s in eng.active]
         if decoding:

@@ -34,7 +34,6 @@ PARENTS = {
     "engine.sample": ("engine.decode_batch",),
     "engine.finish": ("engine.decode_batch",),
     "engine.fetch": ("engine.sample", "engine.first_token"),
-    "engine.sample_dispatch": ("engine.sample", "engine.first_token"),
 }
 
 

@@ -19,6 +19,7 @@ from repro.kernels.attention.flash import flash_attention_bhsd
 from repro.kernels.attention.paged import (paged_attention_bhd,
                                            paged_prefill_attention_btd)
 from repro.models import transformer as T
+from repro.serving.sampling import sample_step
 
 CFG = configs.get("yi-6b")
 H, KV, D = CFG.n_heads, CFG.n_kv_heads, CFG.head_dim
@@ -120,6 +121,27 @@ def test_decode_step_paged_compiles(one_chip):
     out = fn.lower(params, pages, batch).compile()
     logits = out.out_info[0]
     assert logits.shape == (B, cfg.vocab_size)
+
+
+def test_decode_step_with_sampler_compiles(one_chip):
+    """The engines' decode program: the paged step with the device
+    sampler folded in, returning (B,) int32 tokens."""
+    cfg, params, pages = _step_inputs(one_chip)
+    batch = {"tokens": _i32(one_chip, B, 1),
+             "block_tables": _i32(one_chip, B, TABLE),
+             "positions": _i32(one_chip, B),
+             "write_rows": _i32(one_chip, B),
+             "write_offs": _i32(one_chip, B),
+             "temperature": jax.ShapeDtypeStruct((B,), jnp.float32,
+                                                 sharding=one_chip),
+             "key_data": jax.ShapeDtypeStruct((B, 2), jnp.uint32,
+                                              sharding=one_chip)}
+    fn = jax.jit(lambda p, pg, b: sample_step(T.decode_step_paged, p, pg,
+                                              b, cfg),
+                 donate_argnums=(1,))
+    out = fn.lower(params, pages, batch).compile()
+    tokens = out.out_info[0]
+    assert tokens.shape == (B,) and tokens.dtype == jnp.int32
 
 
 def test_prefill_chunk_compiles(one_chip):
