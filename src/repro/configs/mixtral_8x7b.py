@@ -1,7 +1,9 @@
-"""mixtral-8x7b: MoE 8 experts top-2, GQA, SWA.
+"""mixtral-8x7b: MoE 8 experts top-2, GQA, full attention.
 [arXiv:2401.04088; hf]
 
 32L d_model=4096 32H (GQA kv=8) d_ff=14336 vocab=32000, 8e top-2.
+The published config.json has `sliding_window: null`: every layer
+attends the whole context.
 """
 
 from repro.models.config import ArchConfig
@@ -17,7 +19,7 @@ CONFIG = ArchConfig(
     vocab_size=32000,
     n_experts=8,
     top_k=2,
-    sliding_window=4096,      # mixtral SWA (sub-quadratic path)
+    sliding_window=0,         # published null: full attention
     rope_theta=1.0e6,
     microbatch_per_device=1,
 )

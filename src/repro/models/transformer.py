@@ -4,7 +4,8 @@ Families and their layer stacks (all scan-over-layers with stacked
 params, remat-wrapped when cfg.remat):
 
   dense / audio   L x [norm, GQA-attn, norm, SwiGLU]
-  moe             L x [norm, GQA-attn, norm, top-2 MoE]
+  moe             L x [norm, GQA-attn, norm, top-k MoE]
+                  (training: GShard dispatch; serving: dropless)
   ssm             L x [norm, mamba1]
   hybrid (zamba2) G groups x [E mamba2 layers, shared attn block] + tail
                   — ONE shared attention block's weights reused by all
@@ -24,7 +25,14 @@ Entry points:
                                             (per-slot position clocks
                                             over AGAS block tables,
                                             DESIGN.md §4a)
+  decode_step_paged_load(...)               -> (logits, pages, load)
+                                            (the same step, also
+                                            returning the tokens each
+                                            expert of each layer
+                                            computed: (L, E) int32, or
+                                            None for dense families)
   prefill_chunk(params, pages, batch, cfg)  -> (logits, pages)
+  prefill_chunk_load(...)                   -> (logits, pages, load)
                                             (resumable chunked prefill:
                                             one page-aligned chunk of a
                                             prompt attends the pages of
@@ -273,6 +281,34 @@ def _mlp_block(lp: Params, x, cfg: ArchConfig):
     return swiglu(lp["mlp"], rmsnorm(lp["mlp_norm"], x, cfg.norm_eps))
 
 
+def _ffn(params: Params, cfg: ArchConfig):
+    """(the layer stack to scan, ffn(lp, x, live=None) -> (update,
+    load)): the SwiGLU block, load None; for the moe family the
+    dropless expert layer, load (E,) int32, with `live` (B, S) marking
+    the rows that are tokens.  The expert weights of every layer stay
+    out of the scanned stack, which carries each layer's index instead:
+    the grouped matmuls read them in place, where a slice of them
+    inside the scan is copied out first (a whole layer's experts, 2.8
+    GB at Mixtral's widths, every layer)."""
+    if cfg.family != "moe":
+        return params["layers"], \
+            lambda lp, x, live=None: (_mlp_block(lp, x, cfg), None)
+    moe = params["layers"]["moe"]
+    experts = {k: moe[k] for k in ("wi", "wg", "wdown")}
+    layers = dict(params["layers"], moe={
+        "router": moe["router"],
+        "layer": jnp.arange(cfg.n_layers, dtype=jnp.int32)})
+
+    def ffn(lp, x, live=None):
+        h = rmsnorm(lp["mlp_norm"], x, cfg.norm_eps)
+        y, load = moe_mod.moe_dropless(
+            dict(experts, router=lp["moe"]["router"]),
+            h.reshape(-1, h.shape[-1]), cfg.top_k, lp["moe"]["layer"],
+            live=None if live is None else live.reshape(-1))
+        return y.reshape(x.shape), load
+    return layers, ffn
+
+
 def _shared_attn_apply(sp: Params, adapter: Params, x, x0,
                        cfg: ArchConfig, positions, use_pallas=False):
     wide = dataclasses.replace(
@@ -489,19 +525,14 @@ def prefill(params: Params, batch: Dict[str, Any], cfg: ArchConfig,
     }
 
     if fam in ("dense", "audio", "moe"):
+        layers, ffn = _ffn(params, cfg)
+
         def layer(x, lp):
             o, (k, v) = _attn_block(lp, x, cfg, cos, sin,
                                     use_pallas=use_pallas)
             x = x + o
-            if fam == "moe":
-                h = rmsnorm(lp["mlp_norm"], x, cfg.norm_eps)
-                mo, _ = moe_mod.moe_apply(lp["moe"], h, cfg, tp)
-                x = x + mo
-            else:
-                x = x + _mlp_block(lp, x, cfg)
-            return x, (trim(k), trim(v))
-        x, (ks, vs) = jax.lax.scan(_maybe_remat(layer, cfg), x,
-                                   params["layers"])
+            return x + ffn(lp, x)[0], (trim(k), trim(v))
+        x, (ks, vs) = jax.lax.scan(_maybe_remat(layer, cfg), x, layers)
         cache["k"], cache["v"] = ks, vs
     elif fam == "ssm":
         def layer(x, lp):
@@ -678,20 +709,16 @@ def decode_step(params: Params, cache: Dict[str, Any],
     fam = cfg.family
 
     if fam in ("dense", "audio", "moe"):
+        layers, ffn = _ffn(params, cfg)
+
         def layer(x, lkv):
             lp, k_c, v_c = lkv
             o, k_c, v_c = _decode_attn(lp, x, cfg, cos, sin, k_c, v_c,
                                        aux_len, pos_write)
             x = x + o
-            if fam == "moe":
-                h = rmsnorm(lp["mlp_norm"], x, cfg.norm_eps)
-                mo, _ = moe_mod.moe_apply(lp["moe"], h, cfg, tp)
-                x = x + mo
-            else:
-                x = x + _mlp_block(lp, x, cfg)
-            return x, (k_c, v_c)
+            return x + ffn(lp, x)[0], (k_c, v_c)
         x, (k_new, v_new) = jax.lax.scan(
-            layer, x, (params["layers"], cache["k"], cache["v"]))
+            layer, x, (layers, cache["k"], cache["v"]))
         cache = dict(cache, k=k_new, v=v_new)
     elif fam == "ssm":
         def layer(x, lst):
@@ -829,6 +856,16 @@ def decode_step_paged(params: Params, pages: Dict[str, Any],
                       batch: Dict[str, Any], cfg: ArchConfig,
                       tp: int = 1, use_pallas: bool = False
                       ) -> Tuple[jnp.ndarray, Dict[str, Any]]:
+    """`decode_step_paged_load` without the expert load."""
+    return decode_step_paged_load(params, pages, batch, cfg, tp,
+                                  use_pallas)[:2]
+
+
+def decode_step_paged_load(params: Params, pages: Dict[str, Any],
+                           batch: Dict[str, Any], cfg: ArchConfig,
+                           tp: int = 1, use_pallas: bool = False
+                           ) -> Tuple[jnp.ndarray, Dict[str, Any],
+                                      Optional[jnp.ndarray]]:
     """One decode step over block tables with per-slot position clocks.
 
     batch: tokens (B, 1); block_tables (B, P) int32 physical page
@@ -838,7 +875,10 @@ def decode_step_paged(params: Params, pages: Dict[str, Any],
     (idle slots point at the pool's null row, which no mask ever
     reads).  Sliding windows are enforced as absolute-position masks —
     pages are never trimmed, so RoPE phases baked at write time stay
-    valid.  Returns (logits (B, V) f32, new pages).
+    valid.  Returns (logits (B, V) f32, new pages, load): for the moe
+    family `load` (L, E) int32 counts the decoding slots each expert of
+    each layer computed (slots writing the null row — idle, or prefill
+    passengers — are routed to no expert); None for the others.
 
     Pages may be sharded across AGAS localities (DESIGN.md §4c):
     6-d ``pages["k"]`` of (L, n_shards, R, ps, KV, D) with block-table
@@ -865,7 +905,11 @@ def decode_step_paged(params: Params, pages: Dict[str, Any],
     # per-slot RoPE phases: (B, 1, rot/2) broadcasting over heads
     cos, sin = att.rope_angles(positions[:, None], max(rot, 2),
                                cfg.rope_theta)
-    fam = cfg.family
+    layers, ffn = _ffn(params, cfg)
+    live = None
+    if cfg.family == "moe":
+        rows = pages["k"].shape[-4]          # a shard's null row: rows-1
+        live = (write_rows % rows != rows - 1)[:, None]
 
     def layer(x, lkv):
         lp, kp, vp = lkv
@@ -885,19 +929,14 @@ def decode_step_paged(params: Params, pages: Dict[str, Any],
         o = paged_attention(q, kp, vp, tables, positions,
                             window=cfg.sliding_window)
         x = x + o.reshape(b, 1, -1) @ lp["attn"]["wo"]
-        if fam == "moe":
-            hh = rmsnorm(lp["mlp_norm"], x, cfg.norm_eps)
-            mo, _ = moe_mod.moe_apply(lp["moe"], hh, cfg, tp)
-            x = x + mo
-        else:
-            x = x + _mlp_block(lp, x, cfg)
-        return x, (kp, vp)
+        mo, load = ffn(lp, x, live)
+        return x + mo, (kp, vp, load)
 
-    x, (k_new, v_new) = jax.lax.scan(
-        layer, x, (params["layers"], pages["k"], pages["v"]))
+    x, (k_new, v_new, load) = jax.lax.scan(
+        layer, x, (layers, pages["k"], pages["v"]))
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = logits_fn(params, x[:, 0])
-    return logits, dict(pages, k=k_new, v=v_new)
+    return logits, dict(pages, k=k_new, v=v_new), load
 
 
 def prefill_chunk(params: Params, pages: Dict[str, Any],
@@ -905,6 +944,17 @@ def prefill_chunk(params: Params, pages: Dict[str, Any],
                   tp: int = 1, use_pallas: bool = False,
                   all_hidden: bool = False
                   ) -> Tuple[jnp.ndarray, Dict[str, Any]]:
+    """`prefill_chunk_load` without the expert load."""
+    return prefill_chunk_load(params, pages, batch, cfg, tp, use_pallas,
+                              all_hidden)[:2]
+
+
+def prefill_chunk_load(params: Params, pages: Dict[str, Any],
+                       batch: Dict[str, Any], cfg: ArchConfig,
+                       tp: int = 1, use_pallas: bool = False,
+                       all_hidden: bool = False
+                       ) -> Tuple[jnp.ndarray, Dict[str, Any],
+                                  Optional[jnp.ndarray]]:
     """Resumable chunked prefill: one page-aligned chunk of a prompt
     consumes and extends the paged KV cache (DESIGN.md §4b).
 
@@ -917,8 +967,10 @@ def prefill_chunk(params: Params, pages: Dict[str, Any],
     the pool's null row substituted for prefix-shared pages (their
     content already exists and must not be rewritten) and for pages
     past a partial final chunk; last_index () int32 chunk-local index
-    whose hidden state feeds the returned logits (only meaningful on a
-    prompt's final chunk; earlier chunks ignore it).
+    of the chunk's last token, whose hidden state feeds the returned
+    logits (only a prompt's final chunk reads them).  Rows past it are
+    right-padding, which the expert layer (moe family) routes to no
+    expert.
 
     Query t attends key positions <= start + t: causal over every
     earlier chunk's pages and within the chunk itself — the chunk's
@@ -927,10 +979,11 @@ def prefill_chunk(params: Params, pages: Dict[str, Any],
     the final partial page beyond the slot's clock; masks never read
     it, and the first decode write overwrites it (same invariant as
     the whole-prompt attach path).  Returns (logits (B, V) f32, new
-    pages); with ``all_hidden=True`` the post-norm hidden (B, C, D)
-    replaces the logits (`last_index` ignored) — callers index the
-    last position themselves and checkpoint the chunk's page-boundary
-    activations for compute skip (§4e).
+    pages, load); with ``all_hidden=True`` the post-norm hidden
+    (B, C, D) replaces the logits — callers index the last position
+    themselves and checkpoint the chunk's page-boundary activations for
+    compute skip (§4e).  `load` is as in `decode_step_paged_load`, over
+    the chunk's tokens.
     """
     if cfg.family not in PAGED_FAMILIES:
         raise ValueError(
@@ -954,7 +1007,10 @@ def prefill_chunk(params: Params, pages: Dict[str, Any],
     positions = start[:, None] + jnp.arange(c)[None, :]    # (B, C)
     rot = int(cfg.head_dim * cfg.rope_fraction) if cfg.n_heads else 2
     cos, sin = att.rope_angles(positions, max(rot, 2), cfg.rope_theta)
-    fam = cfg.family
+    layers, ffn = _ffn(params, cfg)
+    live = None
+    if cfg.family == "moe":
+        live = jnp.broadcast_to(jnp.arange(c) <= last_index, (b, c))
 
     def layer(x, lkv):
         lp, kp, vp = lkv
@@ -977,23 +1033,18 @@ def prefill_chunk(params: Params, pages: Dict[str, Any],
         o = paged_prefill_attention(q, kp, vp, tables, start,
                                     window=cfg.sliding_window)
         x = x + o.reshape(b, c, -1) @ lp["attn"]["wo"]
-        if fam == "moe":
-            hh = rmsnorm(lp["mlp_norm"], x, cfg.norm_eps)
-            mo, _ = moe_mod.moe_apply(lp["moe"], hh, cfg, tp)
-            x = x + mo
-        else:
-            x = x + _mlp_block(lp, x, cfg)
-        return x, (kp, vp)
+        mo, load = ffn(lp, x, live)
+        return x + mo, (kp, vp, load)
 
-    x, (k_new, v_new) = jax.lax.scan(
-        layer, x, (params["layers"], pages["k"], pages["v"]))
+    x, (k_new, v_new, load) = jax.lax.scan(
+        layer, x, (layers, pages["k"], pages["v"]))
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    pages = dict(pages, k=k_new, v=v_new)
     if all_hidden:
-        return x, dict(pages, k=k_new, v=v_new)
+        return x, pages, load
     out = jax.lax.dynamic_index_in_dim(x, last_index, axis=1,
                                        keepdims=False)
-    logits = logits_fn(params, out)
-    return logits, dict(pages, k=k_new, v=v_new)
+    return logits_fn(params, out), pages, load
 
 
 def resume_prefill(params: Params, hidden: jnp.ndarray) -> jnp.ndarray:

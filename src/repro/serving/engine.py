@@ -105,6 +105,14 @@ STEP_SPANS = (
     "engine.decode_inputs", "engine.decode_dispatch", "engine.sample",
     "engine.finish", "engine.fetch")
 
+#: Expert-load keys of a counter row (moe family), summed over the
+#: step's programs: the (token, expert) pairs computed, the (layer,
+#: expert) pairs that computed any token, the busiest expert's pairs
+#: summed over layers, and the decode program's part of the second.
+#: Registry counters `moe.routed`, `moe.experts_hit`, ... alike.
+MOE_KEYS = ("moe_routed", "moe_experts_hit", "moe_load_max",
+            "moe_decode_experts_hit")
+
 
 class _EngineBase:
     """Queue intake, bucketed prefill, sampling, and the run loop."""
@@ -137,6 +145,14 @@ class _EngineBase:
         self._syncs = 0
         self._fetch_s = 0.0
         self._step_t0 = time.perf_counter()
+        # moe: the programs return their (L, E) expert load beside the
+        # tokens they sample.  A load that no read takes in its own
+        # step is held, (counter row, device array, from the decode
+        # program), until the next read takes it along; `_step_load`
+        # sums this step's (`MOE_KEYS`, where `_moe_counts`)
+        self._moe_counts = False
+        self._held: List[tuple] = []
+        self._step_load: Dict[str, int] = {}
 
     # -- observability ------------------------------------------------
     def set_tracer(self, tracer) -> None:
@@ -257,17 +273,48 @@ class _EngineBase:
         `host_syncs` and `fetch_ms`."""
         with self.trace.span("engine", "fetch", kind="copy"):
             t = time.perf_counter()
-            out = np.asarray(x)
+            out = jax.device_get(x) if isinstance(x, list) \
+                else np.asarray(x)
             self._fetch_s += time.perf_counter() - t
         self._syncs += 1
         return out
+
+    def _read(self, tokens, load=None, decode: bool = False
+              ) -> np.ndarray:
+        """A program's sampled tokens, in one `_fetch` together with its
+        expert load (moe; the decode program's where `decode`) and
+        every held one; the loads go to the counters."""
+        if load is not None:
+            self._held.append((len(self.counters), load, decode))
+        if not self._held:
+            return self._fetch(tokens)
+        held, self._held = self._held, []
+        out, *loads = self._fetch([tokens] + [a for _, a, _ in held])
+        for (row, _, dec), a in zip(held, loads):
+            self._note_load(row, a, dec)
+        return out
+
+    def _note_load(self, row: int, load: np.ndarray,
+                   decode: bool = False) -> None:
+        """Add one program's (L, E) expert load to counter row `row`
+        (this step's, while that row is not yet written) and the
+        registry."""
+        hit = int((load > 0).sum())
+        vals = (int(load.sum()), hit, int(load.max(axis=1).sum()),
+                hit if decode else 0)
+        row_d = self.counters[row] if row < len(self.counters) \
+            else self._step_load
+        for key, v in zip(MOE_KEYS, vals):
+            row_d[key] = row_d.get(key, 0) + v
+            self.metrics.counter("moe." + key[4:]).inc(v)
 
     def _step_telemetry(self) -> dict:
         """A counter row's host-time keys: the step's fetches, the host
         time blocked in them, and the step's host wall time so far."""
         return {"host_syncs": self._syncs,
                 "fetch_ms": self._fetch_s * 1e3,
-                "step_ms": (time.perf_counter() - self._step_t0) * 1e3}
+                "step_ms": (time.perf_counter() - self._step_t0) * 1e3,
+                **self._step_load}
 
     def _sample(self, logits: jnp.ndarray, req: Request,
                 n_gen: int) -> int:
@@ -388,6 +435,8 @@ class _EngineBase:
         wall-clock into the child spans' kinds (obs/attribution.py)."""
         self._syncs, self._fetch_s = 0, 0.0
         self._step_t0 = time.perf_counter()
+        self._step_load = dict.fromkeys(MOE_KEYS, 0) \
+            if self._moe_counts else {}
         with self.trace.span("engine", "step") as sp:
             n = self._step()
             sp.args["ran"] = n
@@ -633,9 +682,12 @@ class PagedServingEngine(_EngineBase):
         self._rebalance_tol = int(rebalance_tolerance)
         # donate the page pool: on accelerators the step updates KV
         # pages in place instead of holding input + output copies
+        # moe: the program returns ((tokens, expert load), pages)
+        self._moe_counts = cfg.family == "moe"
+        step = T.decode_step_paged_load if self._moe_counts \
+            else T.decode_step_paged
         self._decode = jax.jit(
-            lambda p, pages, b: sample_step(T.decode_step_paged, p,
-                                            pages, b, cfg),
+            lambda p, pages, b: sample_step(step, p, pages, b, cfg),
             donate_argnums=(1,))
         self._seq = itertools.count()          # admission order
         self.preemptions = 0
@@ -1205,13 +1257,14 @@ class PagedServingEngine(_EngineBase):
                               for slot in slots), self.slots),
                          **self.kvc.batch_inputs()}
             with span("engine", "decode_dispatch", kind="compute"):
-                next_tokens, pages = self._decode(
+                out, pages = self._decode(
                     self.params, self.kvc.pool.pages, batch)
                 self.kvc.pool.pages = pages
+                next_tokens, load = out if self._moe_counts else (out, None)
             done: List[int] = []
             now = time.perf_counter()
             with span("engine", "sample", kind="compute"):
-                toks = self._fetch(next_tokens)
+                toks = self._read(next_tokens, load, decode=True)
                 for slot in slots:
                     st = self.active[slot]
                     self.kvc.advance(slot)
@@ -1451,7 +1504,7 @@ class ChunkedPagedServingEngine(PagedServingEngine):
 
         def chunk_fn(p, pages, toks, tables, start, rows, last,
                      temperature, key_data):
-            x, pages = T.prefill_chunk(p, pages, {
+            x, pages, load = T.prefill_chunk_load(p, pages, {
                 "tokens": toks, "block_tables": tables, "start": start,
                 "chunk_rows": rows, "last_index": last}, cfg,
                 all_hidden=True)
@@ -1459,7 +1512,7 @@ class ChunkedPagedServingEngine(PagedServingEngine):
                                                keepdims=False)
             tok = sample_tokens(T.logits_fn(p, out), temperature,
                                 key_data)
-            return tok, out, x[:, ps - 1::ps], pages
+            return (tok, load), out, x[:, ps - 1::ps], pages
         self._chunk_step = jax.jit(chunk_fn, donate_argnums=(1,))
         # the role composition (DESIGN.md §4f): a role-agnostic token-
         # budget scheduler drives a prefill role and a decode role.
@@ -1646,7 +1699,7 @@ class ChunkedPagedServingEngine(PagedServingEngine):
                       jnp.int32(take - 1),
                       s["temperature"], s["key_data"])
         with self.trace.span("engine", "chunk_dispatch", kind="compute"):
-            tok, hlast, bh, pages = self._chunk_step(
+            (tok, load), hlast, bh, pages = self._chunk_step(
                 self.params, self.kvc.pool.pages, *inputs)
             self.kvc.pool.pages = pages
         if self._prefix_skip:
@@ -1658,6 +1711,8 @@ class ChunkedPagedServingEngine(PagedServingEngine):
                                         self._fetch(hlast[0]))
         st["pos"] = end
         st["prefill_s"] += time.perf_counter() - t0
+        if load is not None:           # read with the next tokens read
+            self._held.append((len(self.counters), load, False))
         if end == st["real"]:
             with self.trace.span("engine", "first_token",
                                  kind="compute"):
@@ -1673,7 +1728,7 @@ class ChunkedPagedServingEngine(PagedServingEngine):
         now = time.perf_counter()
         st["phase"] = "decode"
         st["t0"] = now
-        st["tokens"].append(int(self._fetch(tok)[0]))
+        st["tokens"].append(int(self._read(tok)[0]))
         self._first_token(st, now)
         if self._stopped(st["req"], st["tokens"]):
             self._finish(self.active.pop(slot))
@@ -1850,7 +1905,7 @@ class DisaggChunkedServingEngine(ChunkedPagedServingEngine):
         flipping the phase in place."""
         now = time.perf_counter()
         st["t0"] = now
-        st["tokens"].append(int(self._fetch(tok)[0]))
+        st["tokens"].append(int(self._read(tok)[0]))
         self._first_token(st, now)
         if self._stopped(st["req"], st["tokens"]):
             self._finish(self.active.pop(slot))

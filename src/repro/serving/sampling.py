@@ -67,12 +67,15 @@ def sample_tokens(logits: jnp.ndarray, temperature: jnp.ndarray,
 
 def sample_step(step, params, state, batch: dict, cfg):
     """Run a model step whose batch also carries the sampler's inputs
-    (`sampler_inputs`' keys) and sample its logits: (tokens, state)."""
+    (`sampler_inputs`' keys) and sample its logits: (tokens, state),
+    or ((tokens, load), state) for a step that also returns an expert
+    load (`decode_step_paged_load`)."""
     batch = dict(batch)
     temperature = batch.pop("temperature")
     key_data = batch.pop("key_data")
-    logits, state = step(params, state, batch, cfg)
-    return sample_tokens(logits, temperature, key_data), state
+    logits, state, *load = step(params, state, batch, cfg)
+    tokens = sample_tokens(logits, temperature, key_data)
+    return ((tokens, *load) if load else tokens), state
 
 
 #: The sampler alone, for a caller that already holds a prefill's logits

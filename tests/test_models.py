@@ -129,11 +129,12 @@ def test_moe_active_params():
 
 
 def test_long500k_applicability():
+    # mixtral-8x7b attends the whole context (published
+    # sliding_window null), so it is not sub-quadratic
     runnable = [a for a in configs.ARCHS if shape_applicable(
         configs.get(a), SHAPES["long_500k"])[0]]
     assert sorted(runnable) == sorted(
-        ["falcon-mamba-7b", "zamba2-7b", "h2o-danube-3-4b",
-         "mixtral-8x7b"])
+        ["falcon-mamba-7b", "zamba2-7b", "h2o-danube-3-4b"])
 
 
 def test_swa_ring_buffer_decode():

@@ -156,3 +156,29 @@ def test_prefill_chunk_compiles(one_chip):
     out = fn.lower(params, pages, batch).compile()
     logits = out.out_info[0]
     assert logits.shape == (1, cfg.vocab_size)
+
+
+def test_moe_expert_stack_is_read_in_place(one_chip):
+    """The dropless expert layer at Mixtral's widths inside a layer
+    scan, as the paged programs run it: the grouped matmuls read every
+    layer's experts from the stack in place, with no per-layer copy of
+    a layer's experts (2.8 GB in bf16) among the temporaries."""
+    from repro.models import moe as M
+    cfg = configs.get("mixtral-8x7b")
+    e, d, ff, n, layers = cfg.n_experts, cfg.d_model, cfg.d_ff, 128, 2
+    wi, wg, wd, router, x = _shapes(
+        one_chip, ((layers, e, d, ff), BF16), ((layers, e, d, ff), BF16),
+        ((layers, e, ff, d), BF16), ((layers, d, e), jnp.float32),
+        ((n, d), BF16))
+
+    def step(wi, wg, wd, router, x):
+        def layer(x, lr):
+            i, r = lr
+            y, load = M.moe_dropless(
+                {"router": r, "wi": wi, "wg": wg, "wdown": wd}, x,
+                cfg.top_k, layer=i)
+            return x + y, load
+        return jax.lax.scan(layer, x, (jnp.arange(layers), router))
+    out = jax.jit(step).lower(wi, wg, wd, router, x).compile()
+    assert out.out_info[1].shape == (layers, e)
+    assert out.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
