@@ -1,6 +1,6 @@
 """Pallas TPU kernels: paged attention over block tables.
 
-Two entry points share the scheme:
+Two entry points:
 
 * `paged_attention_bhd` — decode: ONE query token per sequence attends
   a KV cache scattered across fixed-size pages.
@@ -9,31 +9,36 @@ Two entry points share the scheme:
   already written for earlier chunks plus the chunk's own freshly
   written pages, causal within the chunk (DESIGN.md §4b).
 
-The block table is a *scalar-prefetch* operand
-(pltpu.PrefetchScalarGridSpec): it is available before the kernel body
-runs, so the k/v index maps dereference it to pick the physical page
-row each grid step DMAs into VMEM — the AGAS lookup compiled into an
-index map, with no gather materialized in HBM.
+Decode reads only the live pages, each once for all its query heads.
+The pools stay in HBM, viewed as (rows, ps * KV, D): a page's tokens
+with their kv heads interleaved, a free reshape of the flat
+(N, ps, KV, D) pool and of the sharded (S, R, ps, KV, D) one, whose
+``locality * R + slot`` rows are then plain row numbers.  One program
+serves the whole batch: for each slot it walks the 128-token blocks
+(`BLOCK_TOKENS`, ppb pages) that hold a position it may see, up to its
+clock and back no further than its sliding window, and copies each
+page of a block with one DMA into a double-buffered VMEM scratch.  The
+next block's copies, the next slot's first block included, start
+before the current block is waited for and computed.  A slot costs at
+least one block, so an idle one (null table row, position 0) costs
+exactly one.  All H query heads score a block's ppb * ps * KV rows in
+one (H, D) x (D, ppb * ps * KV) matmul; a row of kv head g counts only
+for query heads g * n_rep .. (g + 1) * n_rep - 1, so nothing is
+widened.  Scores and the online softmax are float32, P·V takes bf16
+inputs and accumulates in float32: the oracle's precision.
 
-Tiling: grid = (B, nP) with the page axis LAST (sequential);
+Prefill keeps the block table as a *scalar-prefetch* operand whose
+k/v index maps pick the physical page row each grid step DMAs into
+VMEM.  Its grid is (B, nP) with the page axis LAST (sequential);
 online-softmax statistics (m, l) and the output accumulator persist in
 VMEM scratch across the nP steps of one sequence and are flushed on the
-final step (same scheme as flash.py).  Every block spans whole trailing
-dimensions, which is what the TPU lowering requires of a block whose
-trailing dims are not (8, 128)-aligned:
+final step (same scheme as flash.py):
 
   k/v tile: (1, ps, KV, D) — one page, ALL kv heads, DMAed once
-  decode  : q/out (1, H, D); scratch acc (H, D), m/l (H, 1) f32
-  prefill : q/out (1, T, H, D); scratch acc (H, T, D), m/l (H, T, 1)
+  q/out (1, T, H, D); scratch acc (H, T, D), m/l (H, T, 1)
 
-GQA is handled inside the body: kv head g serves query heads
-g*n_rep .. (g+1)*n_rep - 1, so each page is read once per sequence no
-matter how many query heads share it (decode groups those n_rep heads
-into one (n_rep, D) x (D, ps) matmul).  Pages entirely outside the
-slot's valid range — beyond its per-slot position counter (or the
-chunk's last query) or behind its sliding window — are skipped via
-@pl.when, so compute scales with the tokens actually resident, not
-with the table width.
+Pages entirely outside the chunk's valid range — past its last query
+or behind its sliding window — are skipped via @pl.when.
 
 `interpret=None` runs the compiled kernel on a TPU and the Pallas
 interpreter everywhere else; the platform alone decides.
@@ -83,11 +88,12 @@ def _init_stats(acc_ref, m_ref, l_ref):
 
 
 def _online_softmax(q, k, v, mask, scale, acc_ref, m_ref, l_ref, at):
-    """One page's contribution to the running softmax of the query
-    rows `q` (n, D) against k/v (ps, D); `at` indexes their scratch."""
+    """One page's (prefill) or block's (decode) contribution to the
+    running softmax of the query rows `q` (n, D) against k/v (t, D);
+    `at` indexes their scratch."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale   # (n, ps)
+        preferred_element_type=jnp.float32) * scale   # (n, t)
     s = jnp.where(mask, s, NEG_INF)
     m_prev = m_ref[at]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -99,38 +105,91 @@ def _online_softmax(q, k, v, mask, scale, acc_ref, m_ref, l_ref, at):
     m_ref[at] = m_new
 
 
-def _decode_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *, ps, kvh, n_rep, n_pages,
-                   window, scale, sharded):
-    b = pl.program_id(0)
-    p = pl.program_id(1)
+BLOCK_TOKENS = 128          # tokens a decode block brings, all heads
+LANES = 128                 # a TPU vector register's lanes
 
-    @pl.when(p == 0)
-    def _init():
+
+def decode_fits(pages: jnp.ndarray) -> bool:
+    """Whether the decode kernel compiles for pages of this shape: its
+    DMAs and matmuls take whole 128-lane rows, so the head dim must be
+    a multiple of 128."""
+    return pages.shape[-1] % LANES == 0
+
+
+def _decode_kernel(tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+                   k_buf, v_buf, sems, acc_ref, m_ref, l_ref, *,
+                   n_slots, n_cols, ppb, ps, kvh, n_rep, window, scale):
+    bk = ppb * ps                        # tokens a block
+    rows = bk * kvh                      # (token, kv head) rows a block
+    h, d = acc_ref.shape
+
+    def bounds(b):
+        """[lo, hi): the blocks of slot b that hold a visible position,
+        at least one and none past the table, so that every copy
+        started is waited for."""
+        pos = pos_ref[b]
+        hi = jnp.minimum(pos // bk + 1, n_cols // ppb)
+        if window <= 0:
+            return 0, hi
+        return jnp.minimum(jnp.maximum(pos - window + 1, 0) // bk,
+                           hi - 1), hi
+
+    def copies(b, i, s):
+        """Slot b's block i into buffer s: one DMA per page and pool."""
+        base = b * n_cols + i * ppb
+        out = []
+        for j in range(ppb):
+            row = jnp.clip(tables_ref[base + j], 0, k_hbm.shape[0] - 1)
+            out.append(pltpu.make_async_copy(k_hbm.at[row], k_buf.at[s, j],
+                                             sems.at[0, s]))
+            out.append(pltpu.make_async_copy(v_hbm.at[row], v_buf.at[s, j],
+                                             sems.at[1, s]))
+        return out
+
+    # row r of a block is token r // kvh of kv head r % kvh
+    col = jax.lax.broadcasted_iota(jnp.int32, (h, rows), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (h, rows), 0)
+    tok = col // kvh
+    g = col - tok * kvh
+    own = (g * n_rep <= head) & (head < (g + 1) * n_rep)
+
+    for c in copies(0, bounds(0)[0], 0):
+        c.start()
+
+    def slot(b, s):
+        lo, hi = bounds(b)
+        pos = pos_ref[b]
+        q = q_ref[b]
         _init_stats(acc_ref, m_ref, l_ref)
 
-    pos = pos_ref[b]
-    base = p * ps
-    live = base <= pos
-    if window > 0:
-        live &= pos - (base + ps - 1) < window
+        def block(i, s):
+            last = i + 1 >= hi
 
-    @pl.when(live)
-    def _body():
-        j = base + jax.lax.broadcasted_iota(jnp.int32, (n_rep, ps), 1)
-        mask = j <= pos
-        if window > 0:
-            mask &= pos - j < window
-        for g in range(kvh):
-            rows = slice(g * n_rep, (g + 1) * n_rep)
-            _online_softmax(q_ref[0, rows], _page(k_ref, g, sharded),
-                            _page(v_ref, g, sharded), mask, scale,
-                            acc_ref, m_ref, l_ref, rows)
+            @pl.when(jnp.logical_not(last) | (b + 1 < n_slots))
+            def _prefetch():
+                nb = jnp.where(last, b + 1, b)
+                ni = jnp.where(last, bounds(jnp.minimum(nb, n_slots - 1))[0],
+                               i + 1)
+                for c in copies(nb, ni, 1 - s):
+                    c.start()
 
-    @pl.when(p == n_pages - 1)
-    def _flush():
+            for c in copies(b, i, s):
+                c.wait()
+            j = i * bk + tok
+            mask = own & (j <= pos)
+            if window > 0:
+                mask &= pos - j < window
+            _online_softmax(q, k_buf[s].reshape(rows, d),
+                            v_buf[s].reshape(rows, d), mask, scale,
+                            acc_ref, m_ref, l_ref, Ellipsis)
+            return 1 - s
+
+        s = jax.lax.fori_loop(lo, hi, block, s)
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[b] = (acc_ref[...] / l).astype(o_ref.dtype)
+        return s
+
+    jax.lax.fori_loop(0, n_slots, slot, 0)
 
 
 def paged_attention_bhd(q: jnp.ndarray, k_pages: jnp.ndarray,
@@ -141,24 +200,32 @@ def paged_attention_bhd(q: jnp.ndarray, k_pages: jnp.ndarray,
                         interpret: Optional[bool] = None) -> jnp.ndarray:
     """q: (B, H, D); k/v_pages: (N, ps, KV, D) — or (S, R, ps, KV, D)
     for a locality-sharded pool, where block-table rows encode
-    ``locality * R + slot`` and the index map performs the AGAS
-    (locality, slot) decode; block_tables: (B, P) int32 physical rows;
-    positions: (B,) int32 per-slot clocks.  Returns (B, H, D)."""
+    ``locality * R + slot``; block_tables: (B, P) int32 physical rows,
+    any valid row past a slot's position; positions: (B,) int32
+    per-slot clocks.  Returns (B, H, D).  Compiled, D must be a
+    multiple of 128 (`decode_fits`); the interpreter takes any D."""
     b, h, d = q.shape
     ps, kvh = k_pages.shape[-3], k_pages.shape[-2]
     n_tables = block_tables.shape[1]
+    ppb = max(1, min(n_tables, BLOCK_TOKENS // ps))
+    n_cols = -(-n_tables // ppb) * ppb
+    # pad the table to whole blocks with row 0: read, never seen
+    tables = jnp.pad(block_tables.astype(jnp.int32),
+                     ((0, 0), (0, n_cols - n_tables)))
     kern = functools.partial(
-        _decode_kernel, ps=ps, kvh=kvh, n_rep=h // kvh,
-        n_pages=n_tables, window=window, scale=d ** -0.5,
-        sharded=k_pages.ndim == 5)
-    kv_spec = _kv_spec(k_pages)
-    q_spec = pl.BlockSpec((1, h, d), lambda bi, pi, bt, _: (bi, 0, 0))
+        _decode_kernel, n_slots=b, n_cols=n_cols, ppb=ppb, ps=ps, kvh=kvh,
+        n_rep=h // kvh, window=window, scale=d ** -0.5)
+    whole = pl.BlockSpec((b, h, d), lambda i, *_: (0, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    buf = pltpu.VMEM((2, ppb, ps * kvh, d), k_pages.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, n_tables),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=q_spec,
+        grid=(1,),
+        in_specs=[whole, hbm, hbm],
+        out_specs=whole,
         scratch_shapes=[
+            buf, buf,
+            pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((h, d), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
@@ -169,8 +236,9 @@ def paged_attention_bhd(q: jnp.ndarray, k_pages: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret_mode(interpret),
-    )(block_tables.astype(jnp.int32), positions.astype(jnp.int32),
-      q, k_pages, v_pages)
+        name="paged_decode",
+    )(tables.reshape(-1), positions.astype(jnp.int32), q,
+      k_pages.reshape(-1, ps * kvh, d), v_pages.reshape(-1, ps * kvh, d))
 
 
 def _prefill_kernel(bt_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
@@ -224,7 +292,7 @@ def paged_prefill_attention_btd(q: jnp.ndarray, k_pages: jnp.ndarray,
     q: (B, T, H, D) chunk queries; k/v_pages: (N, ps, KV, D) — or
     (S, R, ps, KV, D) for a locality-sharded pool with
     ``locality * R + slot`` row encoding (the AGAS decode lives in the
-    index map, exactly as in the decode kernel); block_tables: (B, P)
+    index map); block_tables: (B, P)
     int32 physical rows; start: (B,) int32 absolute position of
     q[:, 0].  The chunk's own K/V must already be written into its
     pages; query t attends keys at positions <= start + t (and within

@@ -852,18 +852,31 @@ def init_paged_cache(cfg: ArchConfig, n_rows: int, page_size: int,
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
 
+def paged_decode_attention(pages: jnp.ndarray):
+    """The paged decode attention for a pool: the Pallas kernel on a
+    TPU when the pool lives on one device and its head dim is whole
+    128-lane rows, the jnp oracle everywhere else.  A pool partitioned
+    over a mesh keeps the oracle, whose gather GSPMD lowers to
+    collectives; a custom call would not partition."""
+    from repro.kernels.attention.paged import decode_fits
+    if jax.default_backend() == "tpu" and decode_fits(pages) and \
+            jax.typeof(pages).sharding.mesh.size <= 1:
+        from repro.kernels.attention.ops import paged_attention
+        return paged_attention
+    from repro.kernels.attention.ref import paged_attention_ref
+    return paged_attention_ref
+
+
 def decode_step_paged(params: Params, pages: Dict[str, Any],
                       batch: Dict[str, Any], cfg: ArchConfig,
-                      tp: int = 1, use_pallas: bool = False
-                      ) -> Tuple[jnp.ndarray, Dict[str, Any]]:
+                      tp: int = 1) -> Tuple[jnp.ndarray, Dict[str, Any]]:
     """`decode_step_paged_load` without the expert load."""
-    return decode_step_paged_load(params, pages, batch, cfg, tp,
-                                  use_pallas)[:2]
+    return decode_step_paged_load(params, pages, batch, cfg, tp)[:2]
 
 
 def decode_step_paged_load(params: Params, pages: Dict[str, Any],
                            batch: Dict[str, Any], cfg: ArchConfig,
-                           tp: int = 1, use_pallas: bool = False
+                           tp: int = 1
                            ) -> Tuple[jnp.ndarray, Dict[str, Any],
                                       Optional[jnp.ndarray]]:
     """One decode step over block tables with per-slot position clocks.
@@ -889,11 +902,7 @@ def decode_step_paged_load(params: Params, pages: Dict[str, Any],
     if cfg.family not in PAGED_FAMILIES:
         raise ValueError(
             f"paged decode supports {PAGED_FAMILIES}, not {cfg.family!r}")
-    if use_pallas:
-        from repro.kernels.attention.ops import paged_attention
-    else:
-        from repro.kernels.attention.ref import \
-            paged_attention_ref as paged_attention
+    paged_attention = paged_decode_attention(pages["k"])
     tokens = batch["tokens"]
     tables = batch["block_tables"]
     positions = batch["positions"]

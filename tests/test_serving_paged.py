@@ -133,20 +133,42 @@ def test_paged_ref_matches_dense_decode_attention():
                                atol=1e-5)
 
 
-@pytest.mark.parametrize("window", [0, 6])
-@pytest.mark.parametrize("kvh", [1, 2])
-def test_paged_pallas_kernel_matches_ref(window, kvh):
+@pytest.mark.parametrize("window", [0, 100], ids=["full", "window"])
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["flat", "sharded"])
+@pytest.mark.parametrize("n_rep", [1, 4, 8, 16])
+def test_paged_pallas_kernel_matches_ref(n_rep, sharded, window):
+    """The decode kernel against the oracle on 16-token pages, which
+    it walks in 128-token blocks.  Slots sit at position 0, on a page
+    edge, on each side of a block edge and three blocks in (a window
+    of 100 then starts inside the second block); one slot is idle,
+    its table all null row.  Each table is 20 pages wide, three
+    blocks once padded, and a slot's pages past its position point at
+    the null row."""
     from repro.kernels.attention.ops import paged_attention
     from repro.kernels.attention.ref import paged_attention_ref
-    b, h, d, ps, npages, ptab = 3, 4, 16, 8, 9, 4
-    q = jnp.asarray(RNG.normal(size=(b, 1, h, d)), jnp.float32)
-    kp, vp = _rand_pages(npages + 1, ps, kvh, d)
-    tables = jnp.asarray(RNG.integers(0, npages, size=(b, ptab)),
-                         jnp.int32)
-    positions = jnp.asarray([3, 17, 30], jnp.int32)
-    ref = paged_attention_ref(q, kp, vp, tables, positions,
-                              window=window)
-    got = paged_attention(q, kp, vp, tables, positions, window=window)
+    kvh, d, ps, ptab = 2, 16, 16, 20
+    h = kvh * n_rep
+    positions = [0, 15, 127, 128, 300, 0]
+    shards, rows = (2, 24) if sharded else (1, 48)
+    null = rows - 1                      # shard 0's null row
+    live = [r for r in range(shards * rows) if r % rows != rows - 1]
+    order = RNG.permutation(live)
+    tables = np.full((len(positions), ptab), null, np.int32)
+    at = 0
+    for slot, pos in enumerate(positions[:-1]):
+        n = pos // ps + 1
+        tables[slot, :n] = order[at:at + n]
+        at += n
+    pool = (shards, rows, ps, kvh, d) if sharded else (rows, ps, kvh, d)
+    kp = jnp.asarray(RNG.normal(size=pool), jnp.float32)
+    vp = jnp.asarray(RNG.normal(size=pool), jnp.float32)
+    q = jnp.asarray(RNG.normal(size=(len(positions), 1, h, d)),
+                    jnp.float32)
+    args = (q, kp, vp, jnp.asarray(tables),
+            jnp.asarray(positions, jnp.int32))
+    ref = paged_attention_ref(*args, window=window)
+    got = paged_attention(*args, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=1e-5)
 

@@ -288,3 +288,37 @@ def test_mesh_backed_shards_parity_and_ppermute_migration():
         print('MESH_SHARDED_OK', moved)
     """)
     assert "MESH_SHARDED_OK" in out
+
+
+def test_decode_attention_follows_platform_mesh_and_head_dim():
+    """`paged_decode_attention` picks the Pallas kernel only where the
+    platform is a TPU (the test says so: this process runs on the CPU),
+    the pool lives on one device, flat or with its localities on that
+    device, and the head dim is whole 128-lane rows; a pool over a
+    4-device mesh, a 64-wide head or the CPU takes the oracle.  Inside
+    a jitted step the pool is a tracer: its sharding decides."""
+    out = run_sub("""
+        import jax, jax.numpy as jnp
+        from repro.models import transformer as T
+        from repro.kernels.attention.ops import paged_attention
+        from repro.kernels.attention.ref import paged_attention_ref
+        from repro.distributed.sharding import (kv_pool_mesh,
+                                                page_pool_shardings)
+        flat = jnp.zeros((1, 9, 16, 2, 128))
+        local = jnp.zeros((1, 4, 9, 16, 2, 128))
+        meshed = jax.device_put(local, page_pool_shardings(kv_pool_mesh(4)))
+        narrow = jnp.zeros((1, 9, 16, 2, 64))
+
+        def pick(pool):
+            got = []
+            jax.jit(lambda p: got.append(T.paged_decode_attention(p))
+                    or p)(pool)
+            return {paged_attention: 'kernel',
+                    paged_attention_ref: 'oracle'}[got[0]]
+        seen = [pick(p) for p in (flat, local, meshed, narrow)]
+        jax.default_backend = lambda: 'tpu'
+        seen += [pick(p) for p in (flat, local, meshed, narrow)]
+        print('PICKED', ' '.join(seen))
+    """)
+    assert "PICKED oracle oracle oracle oracle " \
+        "kernel kernel oracle oracle" in out

@@ -8,6 +8,7 @@ import time, and every test of this kind stays in this one file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -61,12 +62,26 @@ def _pool(sharded: bool):
     return (4, ROWS // 4, PS, KV, D) if sharded else (ROWS, PS, KV, D)
 
 
+# the cells' decode shapes: slots, table pages, KV heads, pool rows
+DECODE_CELLS = {
+    "yi-6b.chat": (32, 64, 4, 1515),
+    "chatglm3-6b.chat": (32, 64, 2, 3065),
+    "mixtral-8x7b.chat": (64, 64, 8, 2962),
+    "yi-6b.sessions": (16, 128, 4, 1515),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(DECODE_CELLS))
 @pytest.mark.parametrize("sharded", [False, True],
                          ids=["flat", "sharded"])
-def test_paged_decode_kernel_compiles(one_chip, sharded):
-    args = _shapes(one_chip, ((B, H, D), BF16), (_pool(sharded), BF16),
-                   (_pool(sharded), BF16), ((B, TABLE), jnp.int32),
-                   ((B,), jnp.int32))
+def test_paged_decode_kernel_compiles(one_chip, sharded, cell):
+    """The decode kernel at each cell's decode shape: 32 query heads of
+    128 over the cell's KV heads (n_rep 8, 16, 4), 16-token pages."""
+    b, table, kv, rows = DECODE_CELLS[cell]
+    pool = (4, rows // 4, PS, kv, D) if sharded else (rows, PS, kv, D)
+    args = _shapes(one_chip, ((b, H, D), BF16), (pool, BF16),
+                   (pool, BF16), ((b, table), jnp.int32),
+                   ((b,), jnp.int32))
     fn = jax.jit(lambda *a: paged_attention_bhd(*a, interpret=False))
     assert "tpu_custom_call" in fn.lower(*args).compile().as_text()
 
@@ -121,6 +136,34 @@ def test_decode_step_paged_compiles(one_chip):
     out = fn.lower(params, pages, batch).compile()
     logits = out.out_info[0]
     assert logits.shape == (B, cfg.vocab_size)
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_decode_step_paged_places_the_kernel(one_chip, monkeypatch,
+                                             backend):
+    """The decode step at yi-6b.chat's 32 slots x 64 pages, lowered for
+    the described chip.  Where the platform is a TPU (the test says so:
+    the described chip is not this process's backend) the program has
+    the `paged_decode` kernel and no gather of every page of every slot
+    (bf16[2048,16,4,128]); elsewhere it has the oracle's gather."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg, params, _ = _step_inputs(one_chip)
+    b, table, _, rows = DECODE_CELLS["yi-6b.chat"]
+    pool = (1, rows, PS, KV, D)
+    k, v = _shapes(one_chip, (pool, BF16), (pool, BF16))
+    batch = {"tokens": _i32(one_chip, b, 1),
+             "block_tables": _i32(one_chip, b, table),
+             "positions": _i32(one_chip, b),
+             "write_rows": _i32(one_chip, b),
+             "write_offs": _i32(one_chip, b)}
+    fn = jax.jit(lambda p, pg, bt: T.decode_step_paged(p, pg, bt, cfg),
+                 donate_argnums=(1,))
+    hlo = fn.lower(params, {"k": k, "v": v}, batch).compile().as_text()
+    kernel = re.search(r"%paged_decode\S* = .*tpu_custom_call", hlo)
+    every_page = re.search(
+        rf"= bf16\[{b * table},{PS},{KV},{D}\]\S* (gather|fusion)", hlo)
+    assert bool(kernel) == (backend == "tpu")
+    assert bool(every_page) == (backend == "cpu")
 
 
 def test_decode_step_with_sampler_compiles(one_chip):
